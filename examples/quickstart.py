@@ -8,6 +8,8 @@ attention.
 
 Run: PYTHONPATH=src python examples/quickstart.py
 """
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,9 @@ import numpy as np
 from repro.cad import CADConfig, CADSession
 from repro.core import cad_attention, ref_attention
 from repro.core.cost_model import CommModel
+from repro.launch.mesh import device_summary
+
+print(f"device: {json.dumps(device_summary())}")
 
 # --- a packed batch: 2 ranks x 1024 tokens, documents of 1-4 blocks ----
 BLK, D, S = 128, 2, 1024

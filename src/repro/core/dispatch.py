@@ -41,8 +41,6 @@ from repro.core.plan import CADConfig, PingPongPlan
 from repro.obs import server_track
 from repro.obs import trace as obs_trace
 
-from repro.compat import shard_map as _shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class CADContext:
@@ -964,7 +962,7 @@ def cad_attention(q, k, v, seg_q, pos_q, seg_kv, pos_kv, *, ctx,
             plan_ = jax.tree.map(lambda a: a[0], plan_)  # drop local D=1
             return fn(qq_, kk_, vv_, pp_, plan_)
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=in_specs,
             out_specs=P(bspec, None, hspec, None),

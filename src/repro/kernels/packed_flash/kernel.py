@@ -39,8 +39,18 @@ final dk/dv footprint in f32 intermediates — accumulating the repeat
 group in-kernel (q-heads folded into the sequential grid dim) is a
 recorded §Perf follow-up; it changes memory, not semantics.
 
-All kernels are validated in interpret mode against ref.py; on TPU they
-compile with explicit VMEM BlockSpecs.
+Layout: the entry functions keep the token-major ``[.., S, H, dh]``
+arrays of their callers, and hand the kernels a heads-major copy
+(``[.., H, S, dh]``).  Mosaic tiles the last two dims of every block by
+(8, 128) unless a dim is taken whole, and only heads-major blocks end in
+``(blk, dh)``.  Per-token vectors get a unit axis for the same reason:
+q-side ones become ``[.., S, 1]`` columns and kv-side ones ``[.., 1, S]``
+rows, which is also how the kernel bodies broadcast them against the
+``[blk_q, blk_k]`` logits.  Row statistics (max, sum, lse, delta) are
+``[blk_q, 1]`` columns throughout.
+
+All kernels are validated in interpret mode against ref.py, and compiled
+for v5e ahead of time in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -50,8 +60,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 
 NEG_INF = -2.0 ** 30
@@ -66,6 +74,32 @@ def _mxu_dot(a, b):
 LSE_DEAD = 2.0 ** 30   # lse of a fully-masked row: exp(x - LSE_DEAD) == 0
 
 
+def _heads_major(x):
+    """[.., S, H, dh] <-> [.., H, S, dh] (its own inverse)."""
+    return jnp.swapaxes(x, -3, -2)
+
+
+def _col(x):
+    """[.., S] -> [.., S, 1]: a q-side per-token vector as a column."""
+    return x[..., None]
+
+
+def _row(x):
+    """[.., S] -> [.., 1, S]: a kv-side per-token vector as a row."""
+    return x[..., None, :]
+
+
+def _fold_gqa(d_h, hkv, dtype):
+    """Per-q-head [.., Hq, S, dh] grads -> token-major [.., S, Hkv, dh]."""
+    *lead, hq, s, dh = d_h.shape
+    d = d_h.reshape(tuple(lead) + (hkv, hq // hkv, s, dh)).sum(-3)
+    return _heads_major(d).astype(dtype)
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
 def _capped_masked_logits(q, k, m, scale, softcap):
     """Scaled, softcapped, masked logits — shared by fwd and bwd bodies."""
     logits = _mxu_dot(q, k.T) * scale
@@ -76,11 +110,45 @@ def _capped_masked_logits(q, k, m, scale, softcap):
 
 def _ds_from_p(p, dp, delta, logits, m, scale, softcap):
     """dL/d(q k^T): softmax bwd + softcap chain rule + scale."""
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if softcap and softcap > 0:
         sc = jnp.where(m, logits / softcap, 0.0)
         ds = ds * (1.0 - sc * sc)
     return ds * scale
+
+
+def _softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _softmax_update(logits, m, v, m_scr, l_scr, acc_scr):
+    """One kv block of the online softmax; row stats are [blk_q, 1]."""
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
+    p = jnp.exp(logits - m_new)
+    p = jnp.where(m, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + _mxu_dot(p.astype(v.dtype), v)
+    m_scr[...] = m_new
+
+
+def _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    l = l_scr[...]
+    live = m_scr[...] > NEG_INF / 2
+    out = acc_scr[...] / jnp.maximum(l, 1e-30)
+    o_ref[...] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+    if lse_ref is not None:
+        lse = m_scr[...] + jnp.log(jnp.maximum(l, 1e-30))
+        lse_ref[...] = jnp.where(live, lse, LSE_DEAD)
+
+
+def _softmax_scratch(blk_q, dh):
+    return [pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, dh), jnp.float32)]
 
 
 # ----------------------------------------------------------- packed flash
@@ -97,9 +165,7 @@ def _flash_kernel(seg_q_ref, pos_q_ref, seg_k_ref, pos_k_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     # mask-driven live-block pruning (chunk-order block indices; sound
     # for packed docs — see _flash_block_live)
@@ -107,40 +173,53 @@ def _flash_kernel(seg_q_ref, pos_q_ref, seg_k_ref, pos_k_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # [blk_q, dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [blk_k, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        m = _flash_mask(seg_q_ref[0, :], pos_q_ref[0, :],
-                        seg_k_ref[0, :], pos_k_ref[0, :], causal, window,
+        q = q_ref[...].astype(jnp.float32)      # [blk_q, dh]
+        k = k_ref[...].astype(jnp.float32)      # [blk_k, dh]
+        v = v_ref[...].astype(jnp.float32)
+        m = _flash_mask(seg_q_ref[...], pos_q_ref[...],
+                        seg_k_ref[...], pos_k_ref[...], causal, window,
                         sink, rate, blk_q)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[:, None])
-        p = jnp.where(m, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] \
-            + _mxu_dot(p.astype(v.dtype), v)
-        m_scr[...] = m_new
+        _softmax_update(logits, m, v, m_scr, l_scr, acc_scr)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = l_scr[...]
-        live = m_scr[...] > NEG_INF / 2
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where(live[:, None], out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse = m_scr[...] + jnp.log(jnp.maximum(l, 1e-30))
-            lse_ref[0, 0, :] = jnp.where(live, lse, LSE_DEAD)
+        _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _flash_specs(blk_q, blk_k, dh, rep, kv_major=False):
+    """BlockSpecs of the packed-flash operands over the heads-major layout.
+
+    Grid ids are (b, h, i, j) with i the q block and j the kv block, or
+    (b, h, j, i) for the kv-major dk/dv pass (``kv_major``)."""
+    def ids(f):
+        if kv_major:
+            return lambda b_, h, j, i: f(b_, h, i, j)
+        return f
+    return dict(
+        seg_q=pl.BlockSpec((None, blk_q, 1), ids(lambda b_, h, i, j:
+                                                  (b_, i, 0))),
+        seg_k=pl.BlockSpec((None, 1, blk_k), ids(lambda b_, h, i, j:
+                                                  (b_, 0, j))),
+        q=pl.BlockSpec((None, None, blk_q, dh), ids(lambda b_, h, i, j:
+                                                     (b_, h, i, 0))),
+        kv=pl.BlockSpec((None, None, blk_k, dh), ids(lambda b_, h, i, j:
+                                                      (b_, h // rep, j, 0))),
+        kv_q_head=pl.BlockSpec((None, None, blk_k, dh),
+                               ids(lambda b_, h, i, j: (b_, h, j, 0))),
+        row=pl.BlockSpec((None, None, blk_q, 1), ids(lambda b_, h, i, j:
+                                                      (b_, h, i, 0))),
+    )
 
 
 def flash_fwd(q, k, v, seg_q, pos_q, seg_kv, pos_kv, *, causal=True,
               window=0, sink=0, rate=1, softcap=0.0, scale=None,
               blk_q=DEFAULT_BLOCK, blk_k=DEFAULT_BLOCK, interpret=True,
               return_lse=False):
+    """q [B, Sq, Hq, dh]; k/v [B, Skv, Hkv, dh]; seg/pos [B, S] int32.
+
+    Returns out [B, Sq, Hq, dh] and, with ``return_lse``, lse
+    [B, Hq, Sq] float32."""
     b, sq, hq, dh = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     rep = hq // hkv
@@ -151,67 +230,57 @@ def flash_fwd(q, k, v, seg_q, pos_q, seg_kv, pos_kv, *, causal=True,
     if rate > 1:
         assert blk_q == blk_k, "dilated masks need square block tiles"
     nq, nk = sq // blk_q, skv // blk_k
+    sp = _flash_specs(blk_q, blk_k, dh, rep)
 
-    grid = (b, hq, nq, nk)
     kernel = functools.partial(
         _flash_kernel, scale=scale, softcap=softcap, causal=causal,
         window=window, sink=sink, rate=rate, blk_q=blk_q, blk_k=blk_k,
         nk=nk, save_lse=return_lse)
-    out_shape = jax.ShapeDtypeStruct((b, sq, hq, dh), q.dtype)
-    out_specs = pl.BlockSpec((1, blk_q, 1, dh),
-                             lambda b_, h, i, j: (b_, i, h, 0))
+    out_shape = jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype)
+    out_specs = sp["q"]
     if return_lse:
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b, hq, sq), jnp.float32))
-        out_specs = (out_specs,
-                     pl.BlockSpec((1, 1, blk_q),
-                                  lambda b_, h, i, j: (b_, h, i)))
-    return pl.pallas_call(
+                     jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32))
+        out_specs = (out_specs, sp["row"])
+    res = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, blk_q), lambda b_, h, i, j: (b_, i)),
-            pl.BlockSpec((1, blk_q), lambda b_, h, i, j: (b_, i)),
-            pl.BlockSpec((1, blk_k), lambda b_, h, i, j: (b_, j)),
-            pl.BlockSpec((1, blk_k), lambda b_, h, i, j: (b_, j)),
-            pl.BlockSpec((1, blk_q, 1, dh), lambda b_, h, i, j: (b_, i, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, dh),
-                         lambda b_, h, i, j, r=rep: (b_, j, h // r, 0)),
-            pl.BlockSpec((1, blk_k, 1, dh),
-                         lambda b_, h, i, j, r=rep: (b_, j, h // r, 0)),
-        ],
+        grid=(b, hq, nq, nk),
+        in_specs=[sp["seg_q"], sp["seg_q"], sp["seg_k"], sp["seg_k"],
+                  sp["q"], sp["kv"], sp["kv"]],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q, dh), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        scratch_shapes=_softmax_scratch(blk_q, dh),
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
         interpret=interpret,
-    )(seg_q, pos_q, seg_kv, pos_kv, q, k, v)
+    )(_col(seg_q), _col(pos_q), _row(seg_kv), _row(pos_kv),
+      _heads_major(q), _heads_major(k), _heads_major(v))
+    if return_lse:
+        out, lse = res
+        return _heads_major(out), lse[..., 0]
+    return _heads_major(res)
 
 
 # ---------------------------------------------------- packed flash bwd
 def _flash_mask(sq, pq, sk, pk, causal, window, sink=0, rate=1, mblk=0):
     """Token-level mask: segments + causal + MaskSpec terms (DESIGN.md §12).
 
-    ``window``/``sink`` are the sliding family's parameters (sink tokens
-    are the always-visible document head); ``rate``/``mblk`` the dilated
-    family's block stride at granularity ``mblk``.  Positions are
-    in-document, so sink and dilation are exact per document."""
-    m = (sq[:, None] == sk[None, :]) & (sq[:, None] > 0) & (sk[None, :] > 0)
+    q-side ``sq``/``pq`` are [blk_q, 1] columns, kv-side ``sk``/``pk``
+    [1, blk_k] rows.  ``window``/``sink`` are the sliding family's
+    parameters (sink tokens are the always-visible document head);
+    ``rate``/``mblk`` the dilated family's block stride at granularity
+    ``mblk``.  Positions are in-document, so sink and dilation are exact
+    per document."""
+    m = (sq == sk) & (sq > 0) & (sk > 0)
     if causal:
-        m &= pq[:, None] >= pk[None, :]
+        m &= pq >= pk
     if window and window > 0:
-        w = (pq[:, None] - pk[None, :]) < window
+        w = (pq - pk) < window
         if sink and sink > 0:
-            w |= pk[None, :] < sink
+            w |= pk < sink
         m &= w
     if rate and rate > 1:
-        m &= ((pq[:, None] // mblk) - (pk[None, :] // mblk)) % rate == 0
+        m &= ((pq // mblk) - (pk // mblk)) % rate == 0
     return m
 
 
@@ -248,24 +317,22 @@ def _flash_bwd_dq_kernel(seg_q_ref, pos_q_ref, seg_k_ref, pos_k_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        m = _flash_mask(seg_q_ref[0, :], pos_q_ref[0, :],
-                        seg_k_ref[0, :], pos_k_ref[0, :], causal, window,
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        m = _flash_mask(seg_q_ref[...], pos_q_ref[...],
+                        seg_k_ref[...], pos_k_ref[...], causal, window,
                         sink, rate, blk_q)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        lse = lse_ref[0, 0, :]
-        p = jnp.where(m, jnp.exp(logits - lse[:, None]), 0.0)
+        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[0, 0, :], logits, m, scale,
-                        softcap)
+        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
         dq_scr[...] += _mxu_dot(ds, k)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(seg_q_ref, pos_q_ref, seg_k_ref, pos_k_ref,
@@ -285,26 +352,24 @@ def _flash_bwd_dkv_kernel(seg_q_ref, pos_q_ref, seg_k_ref, pos_k_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        m = _flash_mask(seg_q_ref[0, :], pos_q_ref[0, :],
-                        seg_k_ref[0, :], pos_k_ref[0, :], causal, window,
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        m = _flash_mask(seg_q_ref[...], pos_q_ref[...],
+                        seg_k_ref[...], pos_k_ref[...], causal, window,
                         sink, rate, blk_q)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        lse = lse_ref[0, 0, :]
-        p = jnp.where(m, jnp.exp(logits - lse[:, None]), 0.0)
+        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
         dv_scr[...] += _mxu_dot(p.T, do)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[0, 0, :], logits, m, scale,
-                        softcap)
+        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
         dk_scr[...] += _mxu_dot(ds.T, q)
 
     @pl.when(i == nq - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_scr[...]
-        dv_ref[0, :, 0, :] = dv_scr[...]
+        dk_ref[...] = dk_scr[...]
+        dv_ref[...] = dv_scr[...]
 
 
 def flash_bwd(q, k, v, out, lse, do, seg_q, pos_q, seg_kv, pos_kv, *,
@@ -330,65 +395,46 @@ def flash_bwd(q, k, v, out, lse, do, seg_q, pos_q, seg_kv, pos_kv, *,
     # delta_i = rowsum(do * out) — linear precompute shared by both passes
     delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
+    args = (_col(seg_q), _col(pos_q), _row(seg_kv), _row(pos_kv),
+            _heads_major(q), _heads_major(k), _heads_major(v),
+            _heads_major(do), _col(lse), _col(delta))
+    mask_kw = dict(scale=scale, softcap=softcap, causal=causal,
+                   window=window, sink=sink, rate=rate, blk_q=blk_q,
+                   blk_k=blk_k)
+    semantics = _params("parallel", "parallel", "parallel", "arbitrary")
 
-    seg_spec_q = pl.BlockSpec((1, blk_q), lambda b_, h, i, j: (b_, i))
-    seg_spec_k = pl.BlockSpec((1, blk_k), lambda b_, h, i, j: (b_, j))
-    q_spec = pl.BlockSpec((1, blk_q, 1, dh),
-                          lambda b_, h, i, j: (b_, i, h, 0))
-    kv_spec = pl.BlockSpec((1, blk_k, 1, dh),
-                           lambda b_, h, i, j, r=rep: (b_, j, h // r, 0))
-    row_spec = pl.BlockSpec((1, 1, blk_q), lambda b_, h, i, j: (b_, h, i))
-
+    sp = _flash_specs(blk_q, blk_k, dh, rep)
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale,
-                          softcap=softcap, causal=causal, window=window,
-                          sink=sink, rate=rate, blk_q=blk_q, blk_k=blk_k,
-                          nk=nk),
+        functools.partial(_flash_bwd_dq_kernel, nk=nk, **mask_kw),
         grid=(b, hq, nq, nk),
-        in_specs=[seg_spec_q, seg_spec_q, seg_spec_k, seg_spec_k,
-                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, dh), q.dtype),
+        in_specs=[sp["seg_q"], sp["seg_q"], sp["seg_k"], sp["seg_k"],
+                  sp["q"], sp["kv"], sp["kv"], sp["q"], sp["row"],
+                  sp["row"]],
+        out_specs=sp["q"],
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, dh), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=semantics,
         interpret=interpret,
-    )(seg_q, pos_q, seg_kv, pos_kv, q, k, v, do, lse, delta)
+    )(*args)
 
-    # dk/dv pass: grid transposed, q-block dim innermost/sequential; the
-    # index maps see grid ids (b, h, j, i)
-    seg_spec_qT = pl.BlockSpec((1, blk_q), lambda b_, h, j, i: (b_, i))
-    seg_spec_kT = pl.BlockSpec((1, blk_k), lambda b_, h, j, i: (b_, j))
-    q_specT = pl.BlockSpec((1, blk_q, 1, dh),
-                           lambda b_, h, j, i: (b_, i, h, 0))
-    kv_specT = pl.BlockSpec((1, blk_k, 1, dh),
-                            lambda b_, h, j, i, r=rep: (b_, j, h // r, 0))
-    kv_out_specT = pl.BlockSpec((1, blk_k, 1, dh),
-                                lambda b_, h, j, i: (b_, j, h, 0))
-    row_specT = pl.BlockSpec((1, 1, blk_q), lambda b_, h, j, i: (b_, h, i))
+    # dk/dv pass: grid transposed, q-block dim innermost/sequential
+    sp = _flash_specs(blk_q, blk_k, dh, rep, kv_major=True)
     dk_h, dv_h = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                          softcap=softcap, causal=causal, window=window,
-                          sink=sink, rate=rate, blk_q=blk_q, blk_k=blk_k,
-                          nq=nq),
+        functools.partial(_flash_bwd_dkv_kernel, nq=nq, **mask_kw),
         grid=(b, hq, nk, nq),
-        in_specs=[seg_spec_qT, seg_spec_qT, seg_spec_kT, seg_spec_kT,
-                  q_specT, kv_specT, kv_specT, q_specT, row_specT,
-                  row_specT],
-        out_specs=(kv_out_specT, kv_out_specT),
-        out_shape=(jax.ShapeDtypeStruct((b, skv, hq, dh), jnp.float32),
-                   jax.ShapeDtypeStruct((b, skv, hq, dh), jnp.float32)),
+        in_specs=[sp["seg_q"], sp["seg_q"], sp["seg_k"], sp["seg_k"],
+                  sp["q"], sp["kv"], sp["kv"], sp["q"], sp["row"],
+                  sp["row"]],
+        out_specs=(sp["kv_q_head"], sp["kv_q_head"]),
+        out_shape=(jax.ShapeDtypeStruct((b, hq, skv, dh), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hq, skv, dh), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((blk_k, dh), jnp.float32),
                         pltpu.VMEM((blk_k, dh), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=semantics,
         interpret=interpret,
-    )(seg_q, pos_q, seg_kv, pos_kv, q, k, v, do, lse, delta)
-    dk = dk_h.reshape(b, skv, hkv, rep, dh).sum(3).astype(k.dtype)
-    dv = dv_h.reshape(b, skv, hkv, rep, dh).sum(3).astype(v.dtype)
-    return dq, dk, dv
+    )(*args)
+    return (_heads_major(dq), _fold_gqa(dk_h, hkv, k.dtype),
+            _fold_gqa(dv_h, hkv, v.dtype))
 
 
 # ---------------------------------------------------- ragged decode (serve)
@@ -409,9 +455,7 @@ def _ragged_decode_kernel(block_req_ref, kv_len_ref, qmin_ref,  # prefetch
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     req = block_req_ref[i]
     live = req >= 0
@@ -423,35 +467,21 @@ def _ragged_decode_kernel(block_req_ref, kv_len_ref, qmin_ref,  # prefetch
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # [blk_q, dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [blk_k, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        pos = q_pos_ref[0, :]
+        q = q_ref[...].astype(jnp.float32)       # [blk_q, dh]
+        k = k_ref[...].astype(jnp.float32)       # [blk_k, dh]
+        v = v_ref[...].astype(jnp.float32)
+        pos = q_pos_ref[...]                     # [blk_q, 1]
         s_pos = j * blk_k + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_k), 1)
-        m = (pos[:, None] >= 0) & (s_pos < kv_len) \
-            & (pos[:, None] >= s_pos)
+            jnp.int32, (1, blk_k), 1)
+        m = (pos >= 0) & (s_pos < kv_len) & (pos >= s_pos)
         if window and window > 0:
-            m &= (pos[:, None] - s_pos) < window
+            m &= (pos - s_pos) < window
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[:, None])
-        p = jnp.where(m, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] \
-            + _mxu_dot(p.astype(v.dtype), v)
-        m_scr[...] = m_new
+        _softmax_update(logits, m, v, m_scr, l_scr, acc_scr)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = l_scr[...]
-        alive = m_scr[...] > NEG_INF / 2
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where(alive[:, None], out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        _softmax_finalize(o_ref, None, m_scr, l_scr, acc_scr)
 
 
 def ragged_decode_fwd(q_blocks, k_cache, v_cache, block_req, kv_len, q_pos,
@@ -480,57 +510,57 @@ def ragged_decode_fwd(q_blocks, k_cache, v_cache, block_req, kv_len, q_pos,
     qmin = jnp.min(jnp.where(q_pos >= 0, q_pos, jnp.int32(2 ** 31 - 1)),
                    axis=1).astype(jnp.int32)
 
+    def q_index(i, h, j, br, kl, qm):
+        return (i, h, 0, 0)
+
     def kv_index(i, h, j, br, kl, qm, r=rep):
-        return (jnp.maximum(br[i], 0), j, h // r, 0)
+        return (jnp.maximum(br[i], 0), h // r, j, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nq, hq, nk),
         in_specs=[
-            pl.BlockSpec((1, blk_q), lambda i, h, j, br, kl, qm: (i, 0)),
-            pl.BlockSpec((1, blk_q, 1, dh),
-                         lambda i, h, j, br, kl, qm: (i, 0, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, dh), kv_index),
-            pl.BlockSpec((1, blk_k, 1, dh), kv_index),
+            pl.BlockSpec((None, blk_q, 1),
+                         lambda i, h, j, br, kl, qm: (i, 0, 0)),
+            pl.BlockSpec((None, None, blk_q, dh), q_index),
+            pl.BlockSpec((None, None, blk_k, dh), kv_index),
+            pl.BlockSpec((None, None, blk_k, dh), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, dh),
-                               lambda i, h, j, br, kl, qm: (i, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q, dh), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, None, blk_q, dh), q_index),
+        scratch_shapes=_softmax_scratch(blk_q, dh),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ragged_decode_kernel, scale=scale,
                           softcap=softcap, window=window, blk_q=blk_q,
                           blk_k=blk_k, nk=nk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nq, blk_q, hq, dh), q_blocks.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((nq, hq, blk_q, dh), q_blocks.dtype),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(block_req.astype(jnp.int32), kv_len.astype(jnp.int32), qmin,
-      q_pos, q_blocks, k_cache, v_cache)
+      _col(q_pos), _heads_major(q_blocks), _heads_major(k_cache),
+      _heads_major(v_cache))
+    return _heads_major(out)
 
 
 # ------------------------------------------------------- CA-server kernel
 def _ca_mask(pq, pk, causal, window, sink=0, rate=1, mblk=0):
     """Token-level CA-task mask on in-document positions.
 
-    The scheduler guarantees each task's kv range is its own document's
-    prefix, so segments are unneeded; sink/dilated terms (DESIGN.md §12)
-    work directly on the in-document positions."""
-    m = (pq[:, None] >= 0) & (pk[None, :] >= 0)
+    ``pq`` is a [blk, 1] column, ``pk`` a [1, blk] row.  The scheduler
+    guarantees each task's kv range is its own document's prefix, so
+    segments are unneeded; sink/dilated terms (DESIGN.md §12) work
+    directly on the in-document positions."""
+    m = (pq >= 0) & (pk >= 0)
     if causal:
-        m &= pq[:, None] >= pk[None, :]
+        m &= pq >= pk
     if window and window > 0:
-        w = (pq[:, None] - pk[None, :]) < window
+        w = (pq - pk) < window
         if sink and sink > 0:
-            w |= pk[None, :] < sink
+            w |= pk < sink
         m &= w
     if rate and rate > 1:
-        m &= ((pq[:, None] // mblk) - (pk[None, :] // mblk)) % rate == 0
+        m &= ((pq // mblk) - (pk // mblk)) % rate == 0
     return m
 
 
@@ -547,9 +577,17 @@ def _ca_live_mask(q_pos_ref, kv_pos_ref, causal, window, sink, rate, blk):
     of these blocks (DESIGN.md §12)."""
     if not (window or sink or rate > 1):
         return None, None
-    m = _ca_mask(q_pos_ref[0, :], kv_pos_ref[0, :], causal, window,
+    m = _ca_mask(q_pos_ref[...], kv_pos_ref[...], causal, window,
                  sink, rate, blk)
-    return m, jnp.any(m)
+    return m, jnp.max(m.astype(jnp.int32)) > 0
+
+
+def _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window, sink, rate,
+                  blk):
+    if mask is not None:
+        return mask
+    return _ca_mask(q_pos_ref[...], kv_pos_ref[...], causal, window, sink,
+                    rate, blk)
 
 
 def _ca_server_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
@@ -565,9 +603,7 @@ def _ca_server_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     mask, any_live = _ca_live_mask(q_pos_ref, kv_pos_ref, causal, window,
                                    sink, rate, blk)
@@ -577,34 +613,40 @@ def _ca_server_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        m = mask if mask is not None else _ca_mask(
-            q_pos_ref[0, :], kv_pos_ref[0, :], causal, window, sink,
-            rate, blk)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        m = _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window,
+                          sink, rate, blk)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[:, None])
-        p = jnp.where(m, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] \
-            + _mxu_dot(p.astype(v.dtype), v)
-        m_scr[...] = m_new
+        _softmax_update(logits, m, v, m_scr, l_scr, acc_scr)
 
     @pl.when(j == jmax - 1)
     def _finalize():
-        l = l_scr[...]
-        live = m_scr[...] > NEG_INF / 2
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where(live[:, None], out, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse = m_scr[...] + jnp.log(jnp.maximum(l, 1e-30))
-            lse_ref[0, 0, :] = jnp.where(live, lse, LSE_DEAD)
+        _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _ca_specs(blk, dh, rep, n_kv_blocks):
+    """BlockSpecs of the CA-server operands over the heads-major layout,
+    for the (task, head, relative kv block) grid with the per-task kv
+    ranges in scalar prefetch."""
+    def kv_block(t, j, starts):
+        return jnp.minimum(starts[t] + j, n_kv_blocks - 1)
+
+    return dict(
+        q_pos=pl.BlockSpec((None, blk, 1),
+                           lambda t, h, j, st, ln: (t, 0, 0)),
+        kv_pos=pl.BlockSpec((None, 1, blk),
+                            lambda t, h, j, st, ln: (kv_block(t, j, st),
+                                                     0, 0)),
+        q=pl.BlockSpec((None, None, blk, dh),
+                       lambda t, h, j, st, ln: (t, h, 0, 0)),
+        kv=pl.BlockSpec((None, None, blk, dh),
+                        lambda t, h, j, st, ln: (kv_block(t, j, st),
+                                                 h // rep, 0, 0)),
+        row=pl.BlockSpec((None, None, blk, 1),
+                         lambda t, h, j, st, ln: (t, h, 0, 0)),
+    )
 
 
 def ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, *,
@@ -616,58 +658,44 @@ def ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, *,
     q_pos [T,blk]; kv_pos [N,blk].  ``jmax`` bounds the kv blocks any task
     may touch (defaults to N).  window/sink/rate are the MaskSpec terms
     (DESIGN.md §12); kv blocks of a task's prefix that the mask leaves
-    fully dead are skipped via ``_ca_live_mask``'s exact predicate."""
+    fully dead are skipped via ``_ca_live_mask``'s exact predicate.
+    With ``return_lse`` the lse [T, Hq, blk] float32 comes second."""
     T, blk, hq, dh = q_tasks.shape
     N, _, hkv, _ = k_buf.shape
     rep = hq // hkv
     scale = scale if scale is not None else dh ** -0.5
     jmax = jmax or N
-
-    def kv_index(t, h, j, starts, lens, r=rep):
-        blk_i = jnp.minimum(starts[t] + j, N - 1)
-        return (blk_i, 0, h // r, 0)
-
-    def kvpos_index(t, h, j, starts, lens):
-        return (jnp.minimum(starts[t] + j, N - 1), 0)
+    sp = _ca_specs(blk, dh, rep, N)
 
     kernel = functools.partial(
         _ca_server_kernel, scale=scale, softcap=softcap, causal=causal,
         window=window, sink=sink, rate=rate, blk=blk, jmax=jmax,
         save_lse=return_lse)
-    out_shape = jax.ShapeDtypeStruct((T, blk, hq, dh), q_tasks.dtype)
-    out_specs = pl.BlockSpec((1, blk, 1, dh),
-                             lambda t, h, j, st, ln: (t, 0, h, 0))
+    out_shape = jax.ShapeDtypeStruct((T, hq, blk, dh), q_tasks.dtype)
+    out_specs = sp["q"]
     if return_lse:
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((T, hq, blk), jnp.float32))
-        out_specs = (out_specs,
-                     pl.BlockSpec((1, 1, blk),
-                                  lambda t, h, j, st, ln: (t, h, 0)))
+                     jax.ShapeDtypeStruct((T, hq, blk, 1), jnp.float32))
+        out_specs = (out_specs, sp["row"])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T, hq, jmax),
-        in_specs=[
-            pl.BlockSpec((1, blk), lambda t, h, j, st, ln: (t, 0)),
-            pl.BlockSpec((1, blk), kvpos_index),
-            pl.BlockSpec((1, blk, 1, dh), lambda t, h, j, st, ln: (t, 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, dh), kv_index),
-            pl.BlockSpec((1, blk, 1, dh), kv_index),
-        ],
+        in_specs=[sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"], sp["kv"]],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((blk,), jnp.float32),
-            pltpu.VMEM((blk,), jnp.float32),
-            pltpu.VMEM((blk, dh), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(blk, dh),
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(kv_start, kv_len, q_pos, kv_pos, q_tasks, k_buf, v_buf)
+    )(kv_start, kv_len, _col(q_pos), _row(kv_pos), _heads_major(q_tasks),
+      _heads_major(k_buf), _heads_major(v_buf))
+    if return_lse:
+        out, lse = res
+        return _heads_major(out), lse[..., 0]
+    return _heads_major(res)
 
 
 # --------------------------------------------------- CA-server backward
@@ -691,24 +719,21 @@ def _ca_bwd_dq_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        m = mask if mask is not None else _ca_mask(
-            q_pos_ref[0, :], kv_pos_ref[0, :], causal, window, sink,
-            rate, blk)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        m = _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window,
+                          sink, rate, blk)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        lse = lse_ref[0, 0, :]
-        p = jnp.where(m, jnp.exp(logits - lse[:, None]), 0.0)
+        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[0, 0, :], logits, m, scale,
-                        softcap)
+        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
         dq_scr[...] += _mxu_dot(ds, k)
 
     @pl.when(j == jmax - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _ca_bwd_dkv_kernel(kv_start_ref, kv_len_ref,      # scalar prefetch
@@ -738,26 +763,23 @@ def _ca_bwd_dkv_kernel(kv_start_ref, kv_len_ref,      # scalar prefetch
 
     @pl.when(covers)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        m = mask if mask is not None else _ca_mask(
-            q_pos_ref[0, :], kv_pos_ref[0, :], causal, window, sink,
-            rate, blk)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        m = _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window,
+                          sink, rate, blk)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        lse = lse_ref[0, 0, :]
-        p = jnp.where(m, jnp.exp(logits - lse[:, None]), 0.0)
+        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
         dv_scr[...] += _mxu_dot(p.T, do)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[0, 0, :], logits, m, scale,
-                        softcap)
+        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
         dk_scr[...] += _mxu_dot(ds.T, q)
 
     @pl.when(t == n_tasks - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_scr[...]
-        dv_ref[0, :, 0, :] = dv_scr[...]
+        dk_ref[...] = dk_scr[...]
+        dv_ref[...] = dv_scr[...]
 
 
 def ca_server_bwd(q_tasks, k_buf, v_buf, out, lse, do, kv_start, kv_len,
@@ -778,82 +800,65 @@ def ca_server_bwd(q_tasks, k_buf, v_buf, out, lse, do, kv_start, kv_len,
 
     delta = jnp.einsum("tqhd,tqhd->thq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
+    args = (kv_start, kv_len, _col(q_pos), _row(kv_pos),
+            _heads_major(q_tasks), _heads_major(k_buf), _heads_major(v_buf),
+            _heads_major(do), _col(lse), _col(delta))
+    mask_kw = dict(scale=scale, softcap=softcap, causal=causal,
+                   window=window, sink=sink, rate=rate, blk=blk)
+    semantics = _params("parallel", "parallel", "arbitrary")
 
-    def kv_index(t, h, j, starts, lens, r=rep):
-        return (jnp.minimum(starts[t] + j, N - 1), 0, h // r, 0)
-
-    def kvpos_index(t, h, j, starts, lens):
-        return (jnp.minimum(starts[t] + j, N - 1), 0)
-
-    dq_grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, hq, jmax),
-        in_specs=[
-            pl.BlockSpec((1, blk), lambda t, h, j, st, ln: (t, 0)),
-            pl.BlockSpec((1, blk), kvpos_index),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda t, h, j, st, ln: (t, 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, dh), kv_index),
-            pl.BlockSpec((1, blk, 1, dh), kv_index),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda t, h, j, st, ln: (t, 0, h, 0)),
-            pl.BlockSpec((1, 1, blk), lambda t, h, j, st, ln: (t, h, 0)),
-            pl.BlockSpec((1, 1, blk), lambda t, h, j, st, ln: (t, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk, 1, dh),
-                               lambda t, h, j, st, ln: (t, 0, h, 0)),
-        scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32)],
-    )
+    sp = _ca_specs(blk, dh, rep, N)
     dq = pl.pallas_call(
-        functools.partial(_ca_bwd_dq_kernel, scale=scale, softcap=softcap,
-                          causal=causal, window=window, sink=sink,
-                          rate=rate, blk=blk, jmax=jmax),
-        grid_spec=dq_grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, blk, hq, dh), q_tasks.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(kv_start, kv_len, q_pos, kv_pos, q_tasks, k_buf, v_buf, do, lse,
-      delta)
-
-    dkv_grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N, hq, T),
-        in_specs=[
-            pl.BlockSpec((1, blk), lambda n, h, t, st, ln: (t, 0)),
-            pl.BlockSpec((1, blk), lambda n, h, t, st, ln: (n, 0)),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln: (t, 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln, r=rep: (n, 0, h // r, 0)),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln, r=rep: (n, 0, h // r, 0)),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln: (t, 0, h, 0)),
-            pl.BlockSpec((1, 1, blk), lambda n, h, t, st, ln: (t, h, 0)),
-            pl.BlockSpec((1, 1, blk), lambda n, h, t, st, ln: (t, h, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln: (n, 0, h, 0)),
-            pl.BlockSpec((1, blk, 1, dh),
-                         lambda n, h, t, st, ln: (n, 0, h, 0)),
+        functools.partial(_ca_bwd_dq_kernel, jmax=jmax, **mask_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(T, hq, jmax),
+            in_specs=[sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"],
+                      sp["kv"], sp["q"], sp["row"], sp["row"]],
+            out_specs=sp["q"],
+            scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32)],
         ),
-        scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32),
-                        pltpu.VMEM((blk, dh), jnp.float32)],
-    )
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_ca_bwd_dkv_kernel, scale=scale, softcap=softcap,
-                          causal=causal, window=window, sink=sink,
-                          rate=rate, blk=blk, n_tasks=T),
-        grid_spec=dkv_grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((N, blk, hq, dh), jnp.float32),
-                   jax.ShapeDtypeStruct((N, blk, hq, dh), jnp.float32)),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((T, hq, blk, dh), q_tasks.dtype),
+        compiler_params=semantics,
         interpret=interpret,
-    )(kv_start, kv_len, q_pos, kv_pos, q_tasks, k_buf, v_buf, do, lse,
-      delta)
-    dk = dk_h.reshape(N, blk, hkv, rep, dh).sum(3).astype(k_buf.dtype)
-    dv = dv_h.reshape(N, blk, hkv, rep, dh).sum(3).astype(v_buf.dtype)
-    return dq, dk, dv
+    )(*args)
+
+    # dk/dv pass: grid (kv block n, head, task t), tasks innermost
+    def task(n, h, t, st, ln):
+        return (t, h, 0, 0)
+
+    def kv_block(n, h, t, st, ln):
+        return (n, h // rep, 0, 0)
+
+    def kv_block_q_head(n, h, t, st, ln):
+        return (n, h, 0, 0)
+
+    dk_h, dv_h = pl.pallas_call(
+        functools.partial(_ca_bwd_dkv_kernel, n_tasks=T, **mask_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, hq, T),
+            in_specs=[
+                pl.BlockSpec((None, blk, 1),
+                             lambda n, h, t, st, ln: (t, 0, 0)),
+                pl.BlockSpec((None, 1, blk),
+                             lambda n, h, t, st, ln: (n, 0, 0)),
+                pl.BlockSpec((None, None, blk, dh), task),
+                pl.BlockSpec((None, None, blk, dh), kv_block),
+                pl.BlockSpec((None, None, blk, dh), kv_block),
+                pl.BlockSpec((None, None, blk, dh), task),
+                pl.BlockSpec((None, None, blk, 1), task),
+                pl.BlockSpec((None, None, blk, 1), task),
+            ],
+            out_specs=(pl.BlockSpec((None, None, blk, dh), kv_block_q_head),
+                       pl.BlockSpec((None, None, blk, dh), kv_block_q_head)),
+            scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32),
+                            pltpu.VMEM((blk, dh), jnp.float32)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((N, hq, blk, dh), jnp.float32),
+                   jax.ShapeDtypeStruct((N, hq, blk, dh), jnp.float32)),
+        compiler_params=semantics,
+        interpret=interpret,
+    )(*args)
+    return (_heads_major(dq), _fold_gqa(dk_h, hkv, k_buf.dtype),
+            _fold_gqa(dv_h, hkv, v_buf.dtype))

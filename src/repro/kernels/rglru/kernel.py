@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-
 
 def _lru_kernel(a_ref, b_ref, h_ref, carry, *, tile_s):
     s_idx = pl.program_id(2)
@@ -47,10 +44,9 @@ def _lru_kernel(a_ref, b_ref, h_ref, carry, *, tile_s):
         a = a * a_sh
         k *= 2
 
-    h_prev = carry[...]
-    h = b + a * h_prev[None, :]
+    h = b + a * carry[...]                    # carry: [1, tile_w]
     h_ref[0] = h.astype(h_ref.dtype)
-    carry[...] = h[-1]
+    carry[...] = h[tile_s - 1:]
 
 
 def lru_scan(a, b, *, tile_s: int = 256, tile_w: int = 128,
@@ -74,8 +70,8 @@ def lru_scan(a, b, *, tile_s: int = 256, tile_w: int = 128,
         out_specs=pl.BlockSpec((1, tile_s, tile_w),
                                lambda bb, wi, si: (bb, si, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), b.dtype),
-        scratch_shapes=[pltpu.VMEM((tile_w,), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, tile_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

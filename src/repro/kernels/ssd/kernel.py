@@ -18,40 +18,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
-
-
-def _ssd_chunk_kernel(C_ref, B_ref, x_ref, dt_ref, csum_ref, nr_ref,
+def _ssd_chunk_kernel(C_ref, B_ref, x_ref, dt_row_ref, dt_col_ref,
+                      csum_row_ref, csum_col_ref, nr_row_ref, nr_col_ref,
                       y_ref, state_ref, *, chunk):
-    C_ = C_ref[0, 0].astype(jnp.float32)           # [c, N]
-    B_ = B_ref[0, 0].astype(jnp.float32)
-    x = x_ref[0, 0].astype(jnp.float32)            # [c, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)          # [c]
-    csum = csum_ref[0, 0].astype(jnp.float32)
-    nr = nr_ref[0, 0]
+    # per-token vectors arrive twice: as [1, c] rows (the j side of the
+    # [c, c] score matrix) and as [c, 1] columns (the i side)
+    C_ = C_ref[...].astype(jnp.float32)           # [c, N]
+    B_ = B_ref[...].astype(jnp.float32)
+    x = x_ref[...].astype(jnp.float32)            # [c, P]
+    dt_row = dt_row_ref[...].astype(jnp.float32)  # [1, c]
+    dt_col = dt_col_ref[...].astype(jnp.float32)  # [c, 1]
+    csum_col = csum_col_ref[...].astype(jnp.float32)
+    nr_col = nr_col_ref[...]
 
-    li = csum[:, None]
-    lj = csum[None, :]
-    dec = jnp.exp(jnp.clip(li - lj, -80.0, 0.0))
-    iota = jax.lax.iota(jnp.int32, chunk)
-    tri = iota[:, None] >= iota[None, :]
-    same = nr[:, None] == nr[None, :]
+    dec = jnp.exp(jnp.clip(csum_col - csum_row_ref[...].astype(jnp.float32),
+                           -80.0, 0.0))
+    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    same = nr_col == nr_row_ref[...]
     dec = jnp.where(tri & same, dec, 0.0)
 
     scores = jax.lax.dot_general(C_, B_.T, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    w = scores * dec * dt[None, :]
+    w = scores * dec * dt_row
     y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    y_ref[0, 0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
-    live = (nr == nr[-1]).astype(jnp.float32)
-    dec_end = jnp.exp(jnp.clip(csum[-1] - csum, -80.0, 0.0)) * live
-    sB = B_ * (dec_end * dt)[:, None]
+    last = slice(chunk - 1, chunk)
+    live = (nr_col == nr_col[last]).astype(jnp.float32)
+    dec_end = jnp.exp(jnp.clip(csum_col[last] - csum_col, -80.0, 0.0)) \
+        * live
+    sB = B_ * (dec_end * dt_col)
     state = jax.lax.dot_general(sB.T, x, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    state_ref[0, 0] = state.astype(state_ref.dtype)
+    state_ref[...] = state.astype(state_ref.dtype)
 
 
 def ssd_chunk(C_, B_, x, dt, csum, nr, *, interpret=True):
@@ -66,31 +68,33 @@ def ssd_chunk(C_, B_, x, dt, csum, nr, *, interpret=True):
     Cm, Bm, xm, dtm, csm = mh(C_), mh(B_), mh(x), mh(dt), mh(csum)
     nrm = jnp.repeat(nr[:, None], h, axis=1).reshape(bt * h, k, c)
 
-    grid = (bt * h, k)
+    def tile(rows, cols):
+        return pl.BlockSpec((None, None, rows, cols),
+                            lambda i, j: (i, j, 0, 0))
+
+    def row(t):
+        return t[..., None, :]
+
+    def col(t):
+        return t[..., None]
+
     kernel = functools.partial(_ssd_chunk_kernel, chunk=c)
     y, states = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, c, n), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, c, n), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, c, p), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, c), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, c), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, c), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, c, p), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, n, p), lambda i, j: (i, j, 0, 0)),
-        ],
+        grid=(bt * h, k),
+        in_specs=[tile(c, n), tile(c, n), tile(c, p),
+                  tile(1, c), tile(c, 1), tile(1, c), tile(c, 1),
+                  tile(1, c), tile(c, 1)],
+        out_specs=[tile(c, p), tile(n, p)],
         out_shape=[
             jax.ShapeDtypeStruct((bt * h, k, c, p), x.dtype),
             jax.ShapeDtypeStruct((bt * h, k, n, p), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(Cm, Bm, xm, dtm, csm, nrm)
+    )(Cm, Bm, xm, row(dtm), col(dtm), row(csm), col(csm), row(nrm),
+      col(nrm))
 
     def unh(t, tail):  # [Bt*H, K, ...] -> [Bt, K, ..., H, ...]
         t = t.reshape((bt, h, k) + tail)

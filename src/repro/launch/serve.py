@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.cost_model import GridCalibrator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import metrics as obs_metrics
 from repro.parallel import ParallelContext
@@ -304,6 +305,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     daemon = EngineDaemon(build_engine(args), calibrate=args.calibrate)
     srv = make_server(daemon, args.host, args.port)
     print(f"serving {args.arch} on http://{args.host}:{srv.server_port} "

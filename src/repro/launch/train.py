@@ -32,6 +32,8 @@ import json
 from repro.cad import CADSession, available_policies
 from repro.configs import get_config
 from repro.data.pipeline import PipelineConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import device_summary
 from repro.obs import enable_tracing, get_recorder, get_registry
 from repro.parallel import ParallelContext
 from repro.train.trainer import TrainConfig, train
@@ -102,6 +104,8 @@ def main():
                          "(counters/gauges/histograms) to this path "
                          "at exit")
     args = ap.parse_args()
+    print(f"device: {json.dumps(device_summary())}")
+    enable_compile_cache()
 
     if args.trace:
         enable_tracing(capacity=args.trace_capacity)

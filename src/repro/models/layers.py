@@ -455,7 +455,8 @@ def _ssd_chunked(x, dt, log_a, B_, C_, chunk, first=None, ctx=None):
         from repro.kernels.ssd.kernel import ssd_chunk
         y_intra, states = ssd_chunk(
             Cc.astype(jnp.float32), Bc.astype(jnp.float32),
-            xc.astype(jnp.float32), dtc, csum, nr.astype(jnp.int32))
+            xc.astype(jnp.float32), dtc, csum, nr.astype(jnp.int32),
+            interpret=jax.default_backend() != "tpu")
         y_intra = y_intra.astype(jnp.float32)
     else:
         # intra-chunk: contribution of input j to output i (j<=i) decays

@@ -51,7 +51,7 @@ class ShardingRules:
 
 
 def make_rules(mesh: Optional[Mesh], cfg) -> ShardingRules:
-    """Divisibility-aware rules for a ("data","model") or
+    """Divisibility-aware rules for a ("data",), ("data","model") or
     ("pod","data","model") mesh."""
     if mesh is None:
         return ShardingRules()
@@ -62,19 +62,22 @@ def make_rules(mesh: Optional[Mesh], cfg) -> ShardingRules:
     for a in data_axes:
         data_n *= axes[a]
 
-    def div(n, axis, size):
-        return axis if (n and n % size == 0) else None
+    # a data-only mesh has no "model" axis to name
+    model = "model" if "model" in axes else None
 
-    heads = div(getattr(cfg, "n_heads", 0), "model", model_n)
-    kv_heads = div(getattr(cfg, "n_kv_heads", 0), "model", model_n)
-    ffn = div(getattr(cfg, "d_ff", 0), "model", model_n)
+    def div(n, axis, size):
+        return axis if (axis and n and n % size == 0) else None
+
+    heads = div(getattr(cfg, "n_heads", 0), model, model_n)
+    kv_heads = div(getattr(cfg, "n_kv_heads", 0), model, model_n)
+    ffn = div(getattr(cfg, "d_ff", 0), model, model_n)
     dmodel = div(getattr(cfg, "d_model", 0), data_axes, data_n)
-    vocab = div(getattr(cfg, "vocab_size", 0), "model", model_n)
+    vocab = div(getattr(cfg, "vocab_size", 0), model, model_n)
     experts = None
     if getattr(cfg, "moe", None) and cfg.moe.n_experts:
         if cfg.moe.expert_parallel and cfg.moe.n_experts % data_n == 0:
             experts = data_axes
-        ffn = div(cfg.moe.d_ff_expert, "model", model_n)
+        ffn = div(cfg.moe.d_ff_expert, model, model_n)
     return ShardingRules(
         batch=data_axes, seq=None,
         residual_seq="model" if model_n > 1 else None,

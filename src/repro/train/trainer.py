@@ -9,14 +9,14 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.cad import CADSession
 from repro.checkpoint import ckpt
 from repro.data.pipeline import PipelineConfig, raw_batches
 from repro.models import model as M
-from repro.optim.adamw import AdamW, cosine_schedule
-from repro.parallel import ParallelContext
+from repro.optim.adamw import AdamW, AdamWState, cosine_schedule
+from repro.parallel import ParallelContext, param_pspecs
 from repro.train.step import make_train_step
 
 
@@ -45,16 +45,34 @@ class TrainConfig:
                                   # fused jit path only records it
 
 
+def _mesh_shardings(cfg, ctx: ParallelContext, params):
+    """(params, optimizer state, batch) shardings on ``ctx.mesh``: params
+    by the ``ctx.rules`` leaf rules (FSDP over the data axes), AdamW
+    moments like their params, and every batch leaf — plan arrays
+    included — split on its leading, rank-major dim."""
+    mesh = ctx.mesh
+    p_sh = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                        param_pspecs(cfg, params, ctx.rules, mesh),
+                        is_leaf=lambda x: isinstance(x, P))
+    o_sh = AdamWState(step=NamedSharding(mesh, P()), mu=p_sh, nu=p_sh)
+    return p_sh, o_sh, NamedSharding(mesh, P(ctx.rules.batch))
+
+
 def train(cfg, pipe_cfg: PipelineConfig, train_cfg: TrainConfig,
           ctx: Optional[ParallelContext] = None, params=None,
           session: Optional[CADSession] = None) -> Dict[str, Any]:
     """Train ``cfg`` (a ModelConfig); returns final params + history.
+    ``params``, when given, are consumed: the first step donates them.
 
     Pass ``session`` (a :class:`repro.cad.CADSession`) to train with the
     attention service: the session provides the ParallelContext and
     attaches prefetched plans to every batch.  Without a session the
     loop trains on raw packed batches with a plain (or caller-supplied)
-    ``ctx``."""
+    ``ctx``.  When the context carries a mesh, params, optimizer state
+    and batches are placed on it, and the step keeps them there.
+
+    The result holds the final ``params`` and ``opt_state``, the logged
+    ``history`` and the jitted ``step_fn``."""
     faults = pool = None
     if session is not None:
         if train_cfg.fault_schedule:
@@ -81,8 +99,19 @@ def train(cfg, pipe_cfg: PipelineConfig, train_cfg: TrainConfig,
     opt = AdamW(lr=cosine_schedule(train_cfg.peak_lr, train_cfg.warmup,
                                    train_cfg.steps),
                 weight_decay=train_cfg.weight_decay)
-    opt_state = opt.init(params)
-    step_fn = jax.jit(make_train_step(cfg, ctx, opt))
+    # params and optimizer state are donated: each step's outputs reuse
+    # their buffers, so the step never holds two copies of either
+    step = make_train_step(cfg, ctx, opt)
+    if ctx.mesh is None:
+        opt_state = opt.init(params)
+        step_fn = jax.jit(step, donate_argnums=(0, 1))
+    else:
+        p_sh, o_sh, b_sh = _mesh_shardings(cfg, ctx, params)
+        params = jax.device_put(params, p_sh)
+        opt_state = jax.jit(opt.init, out_shardings=o_sh)(params)
+        step_fn = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
+                          out_shardings=(p_sh, o_sh, None),
+                          donate_argnums=(0, 1))
 
     calibrating = (session is not None
                    and session.calibrator is not None
@@ -142,4 +171,5 @@ def train(cfg, pipe_cfg: PipelineConfig, train_cfg: TrainConfig,
                           else session.calibrator)
     finally:
         gen.close()      # stops the plan-prefetch worker, if any
-    return {"params": params, "opt_state": opt_state, "history": history}
+    return {"params": params, "opt_state": opt_state, "history": history,
+            "step_fn": step_fn}

@@ -11,7 +11,6 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
 import sys; sys.path.insert(0, 'src')
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map as _shard_map
 from repro.configs import get_config
 from repro.models import model as M
 from repro.models.model import block_apply
@@ -44,8 +43,8 @@ stage_blocks = split_stages(params['blocks'], N_STAGES)
 h_mb = jnp.stack([
     M._embed(params, cfg, toks[m], ctx) for m in range(N_MICRO)])
 
-from repro.compat import make_mesh
-mesh = make_mesh((N_STAGES,), ('stage',))
+mesh = jax.make_mesh((N_STAGES,), ('stage',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 def body(sp, h_mb_, seg_, pos_):
     sp = jax.tree.map(lambda a: a[0], sp)       # drop local stage dim
@@ -62,7 +61,7 @@ def body(sp, h_mb_, seg_, pos_):
 
     return pipeline_apply(sp, h_mb_, stage_fn, n_stages=N_STAGES)
 
-out_h = jax.jit(_shard_map(
+out_h = jax.jit(jax.shard_map(
     body, mesh=mesh,
     in_specs=(P('stage'), P(), P(), P()),
     out_specs=P(), check_vma=False))(stage_blocks, h_mb, seg, pos)
@@ -87,7 +86,6 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
 import sys; sys.path.insert(0, 'src')
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map as _shard_map
 from repro.core import CADConfig, CADContext, CommModel, ref_attention
 from repro.core.dispatch import _rank_fn
 from repro.pipeline_par import pipeline_apply, tick_schedules
@@ -121,8 +119,8 @@ key = jax.random.PRNGKey(1)
 x_mb = jax.random.normal(key, (N_MICRO, 1, S, H, DH))
 pos_m = jnp.asarray(np.where(segs_mb > 0, poss_mb, -1))[:, None, :]
 
-from repro.compat import make_mesh
-mesh = make_mesh((N_STAGES,), ('stage',))
+mesh = jax.make_mesh((N_STAGES,), ('stage',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 def body(x_mb_, pos_):
     def stage_fn(h, m, tick_plan):
@@ -136,7 +134,7 @@ def body(x_mb_, pos_):
                           lambda h, m, p: stage_fn(h, m, p),
                           n_stages=N_STAGES, plans=plans)
 
-out = jax.jit(_shard_map(
+out = jax.jit(jax.shard_map(
     body, mesh=mesh, in_specs=(P(), P()),
     out_specs=P(), check_vma=False))(x_mb, pos_m)
 

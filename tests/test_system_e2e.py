@@ -1,5 +1,6 @@
 """System-level end-to-end properties."""
 import dataclasses
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,40 @@ def test_make_rules_divisibility():
             assert rules.kv_heads is None
         if cfg.d_ff and cfg.d_ff % 16 == 0 and not cfg.moe:
             assert rules.ffn == "model"
+
+
+def test_make_rules_data_only_mesh():
+    """A ("data",) mesh — one CAD rank per chip — has no "model" axis, so
+    the rules name none; batch, dmodel (FSDP) and the CAD dispatch use the
+    data axis."""
+    class FakeMesh:
+        axis_names = ("data",)
+
+        class _D:
+            shape = (4,)
+        devices = _D()
+
+    rules = make_rules(FakeMesh(), get_config("smollm-360m"))
+    assert (rules.heads, rules.kv_heads, rules.ffn, rules.vocab) \
+        == (None, None, None, None)
+    assert rules.batch == rules.dmodel == rules.cad_axis == ("data",)
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache
+    goes to one fixed directory inside the checkout."""
+    from repro.launch import compile_cache as CC
+    updates = []
+    monkeypatch.setattr(CC.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv(CC.ENV_VAR, str(tmp_path))
+    assert CC.enable_compile_cache() == str(tmp_path)
+    assert updates == []
+    monkeypatch.delenv(CC.ENV_VAR)
+    placed = CC.enable_compile_cache()
+    assert updates == [("jax_compilation_cache_dir", placed)]
+    checkout = Path(__file__).resolve().parents[1]
+    assert Path(placed) == checkout / ".jax_cache"
 
 
 def test_param_pspecs_cover_all_leaves():
