@@ -45,19 +45,22 @@ class PlanPrefetcher:
     the consumer thread at pull time; when it returns True the item is
     re-planned synchronously with ``refresh`` (default: ``fn``) before
     being yielded — the calibration feedback path.  ``stale_refreshes``
-    counts how many pulls re-planned.
+    counts how many pulls re-planned.  ``step_of`` (optional) names the
+    step each ``prefetch.plan`` span is tagged with, from its item.
     """
 
     def __init__(self, source: Iterable[Any], fn: Callable[[Any], Any],
                  depth: int = 2, *,
                  is_stale: Optional[Callable[[Any], bool]] = None,
-                 refresh: Optional[Callable[[Any], Any]] = None):
+                 refresh: Optional[Callable[[Any], Any]] = None,
+                 step_of: Optional[Callable[[Any], int]] = None):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
         self._source = iter(source)
         self._fn = fn
         self._is_stale = is_stale
         self._refresh = refresh if refresh is not None else fn
+        self._step_of = step_of
         self.stale_refreshes = 0
         self._queue: "queue.Queue[Any]" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
@@ -78,12 +81,15 @@ class PlanPrefetcher:
         return False
 
     def _work(self) -> None:
-        rec = obs_trace.get_recorder()
         try:
             for raw in self._source:
                 if self._stop.is_set():
                     return
-                with rec.span("prefetch.plan", "prefetch"):
+                step = self._step_of(raw) if self._step_of else None
+                # looked up per item: tracing may be switched on while
+                # the worker runs
+                with obs_trace.get_recorder().span(
+                        "prefetch.plan", "prefetch", step=step):
                     item = self._fn(raw)
                 if not self._put(item):
                     return

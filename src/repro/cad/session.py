@@ -48,11 +48,32 @@ from repro.core.dispatch import CADContext, iter_plan_tasks, \
     probe_plan_times
 from repro.core.mask import MaskSpec, parse_mask, validate_mask_layout
 from repro.core.plan import CADConfig, PingPongPlan, StepPlan
+from repro.kernels.packed_flash.kernel import ca_grid_cells
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.parallel import ParallelContext, ShardingRules
 
 Plan = Union[StepPlan, PingPongPlan]
+
+
+def grid_counts(plan: Plan, jmax: int, blk: int,
+                mask: Optional[MaskSpec] = None) -> Dict[str, int]:
+    """The CA-server grid cells a planned step launches, per head and
+    layer, and how many run a body (``ca_grid_cells``), summed over
+    ping-pong halves and servers: ``ca_fwd_cells[_live]`` for the forward
+    and dq grid (T tasks, jmax), ``ca_dkv_cells[_live]`` for the dk/dv
+    grid (N kv blocks, T)."""
+    out = dict.fromkeys(("ca_fwd_cells", "ca_fwd_cells_live",
+                         "ca_dkv_cells", "ca_dkv_cells_live"), 0)
+    for p in (list(plan) if isinstance(plan, PingPongPlan) else [plan]):
+        fwd, fwd_live, dkv, dkv_live = ca_grid_cells(
+            p["task_kv_start"], p["task_kv_len"],
+            np.asarray(p["kv_gather"]).shape[1], jmax, blk, mask)
+        out["ca_fwd_cells"] += fwd * fwd_live.size
+        out["ca_fwd_cells_live"] += int(fwd_live.sum())
+        out["ca_dkv_cells"] += dkv * dkv_live.size
+        out["ca_dkv_cells_live"] += int(dkv_live.sum())
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,32 +333,30 @@ class CADSession:
                 self.calibrator.observe_tasks(tasks, seconds, server=s)
 
     # ----------------------------------------------------------- planning
-    def plan(self, segment_ids: np.ndarray) \
-            -> Tuple[Plan, Dict[str, float]]:
+    def plan(self, segment_ids: np.ndarray, *,
+             step: Optional[int] = None) -> Tuple[Plan, Dict[str, Any]]:
         """Plan one step.  ``segment_ids`` is the rank-major [D, T] packed
         layout (T = tokens per rank; 2·nb·blk when ping-pong is on).
         With a calibrator attached, the whole step — both ping-pong
         halves — plans from ONE calibration snapshot, recorded in the
-        stats as ``calib_version`` (+ the per-server speeds used).
+        stats as ``calib_version`` (+ the per-server speeds used).  The
+        stats' ``grid`` counts the CA-server grid cells the step walks
+        (:func:`grid_counts`).
 
         Each call is narrated to the observability layer (DESIGN.md
-        §14): a ``plan.build`` span on the ``planner`` track and the
-        plan-quality gauges — both no-ops unless tracing is enabled /
-        read."""
+        §14): a ``plan.build`` span on the ``planner`` track, tagged
+        with ``step`` (the batch's index in the stream) when given — a
+        no-op unless tracing is enabled."""
         with obs_trace.get_recorder().span("plan.build", "planner",
+                                           step=step,
                                            args={"policy":
                                                  self.plan_policy}):
             plan, stats = self._plan_impl(segment_ids)
-        reg = obs_metrics.get_registry()
-        reg.gauge("cad_plan_load_max_over_mean",
-                  "planned per-server load max/mean").set(
-            stats.get("load_max_over_mean", 0.0))
-        if "calib_version" in stats:
-            reg.gauge("cad_calib_version",
-                      "calibration snapshot version planned from").set(
-                stats["calib_version"])
+        stats["grid"] = grid_counts(plan, self.jmax or self.cfg.nkv,
+                                    self.cfg.blk, self.mask)
         if "pool_epoch" in stats:
-            reg.gauge("cad_pool_epoch", "pool membership epoch").set(
+            obs_metrics.get_registry().gauge(
+                "cad_pool_epoch", "pool membership epoch").set(
                 stats["pool_epoch"])
         return plan, stats
 
@@ -386,9 +405,11 @@ class CADSession:
                 res.stats["load_max_over_mean"])
         return PingPongPlan(*halves), self._annotate(stats, snap, view)
 
-    def plan_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
+    def plan_batch(self, batch: Dict[str, Any], *,
+                   step: Optional[int] = None) -> Dict[str, Any]:
         """Attach ``plan`` + ``schedule_stats`` to one pipeline batch
-        (rows are rank-major: rank r owns rows [r·rpr, (r+1)·rpr))."""
+        (rows are rank-major: rank r owns rows [r·rpr, (r+1)·rpr));
+        ``step`` tags the plan's span (see :meth:`plan`)."""
         segs = np.asarray(batch["segment_ids"])
         if self.pingpong:
             rpr = segs.shape[0] // self.cfg.n_servers
@@ -398,7 +419,7 @@ class CADSession:
                 raise ValueError("ping-pong needs an even number of rows "
                                  f"per rank, got {rpr}")
         segs_rank = segs.reshape(self.cfg.n_servers, -1)
-        plan, stats = self.plan(segs_rank)
+        plan, stats = self.plan(segs_rank, step=step)
         out = dict(batch)
         out["plan"] = plan
         out["schedule_stats"] = stats
@@ -423,15 +444,25 @@ class CADSession:
         must never reach the dispatch."""
         depth = self.prefetch if prefetch is None else prefetch
         if depth <= 0:
-            for batch in batch_iter:
-                yield self.plan_batch(batch)
+            for step, batch in enumerate(batch_iter):
+                yield self.plan_batch(batch, step=step)
             return
-        stale = self._plan_stale if (self.calibrator is not None
-                                     or self.pool is not None) else None
-        pf = PlanPrefetcher(batch_iter, self.plan_batch, depth=depth,
-                            is_stale=stale)
+
+        # items travel as (index in the stream, batch), so a re-plan at
+        # pull keeps the step its plan spans are tagged with
+        def plan(item):
+            step, batch = item
+            return step, self.plan_batch(batch, step=step)
+
+        stale = None
+        if self.calibrator is not None or self.pool is not None:
+            def stale(item):
+                return self._plan_stale(item[1])
+        pf = PlanPrefetcher(enumerate(batch_iter), plan, depth=depth,
+                            is_stale=stale, step_of=lambda item: item[0])
         try:
-            yield from pf
+            for _, batch in pf:
+                yield batch
         finally:
             pf.close()
 

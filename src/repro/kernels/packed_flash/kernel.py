@@ -58,8 +58,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.mask import live_block_mask
 
 
 NEG_INF = -2.0 ** 30
@@ -862,3 +865,32 @@ def ca_server_bwd(q_tasks, k_buf, v_buf, out, lse, do, kv_start, kv_len,
     )(*args)
     return (_heads_major(dq), _fold_gqa(dk_h, hkv, k_buf.dtype),
             _fold_gqa(dv_h, hkv, v_buf.dtype))
+
+
+def ca_grid_cells(kv_start, kv_len, n_kv, jmax, blk, mask=None):
+    """Cells of the grids ``ca_server_fwd`` and ``ca_server_bwd`` launch
+    per head for one task batch, and how many of them run a body; host
+    numpy, for counting plans (``cad/session.py``).
+
+    ``kv_start``/``kv_len`` are [..., T] (leading axes: servers) and
+    ``n_kv`` is the kv blocks each server holds.  Returns ``(fwd_cells,
+    fwd_live, dkv_cells, dkv_live)``: the size of the forward and dq grid
+    (T, jmax) and of the dk/dv grid (N, T), and [...] arrays of their live
+    cells.  Cell (t, j) is live when ``j < kv_len[t]``; cell (n, t) when
+    ``n - kv_start[t]`` lies in ``[0, kv_len[t])``.  With a ``MaskSpec``
+    a live cell must also hold a kv block that ``core.mask``'s
+    ``live_block_mask`` keeps for the task's q block (in-document index
+    ``kv_len - 1``); the kernels test the tokens themselves, so at a
+    sliding window's edge this can count one block per task that they
+    skip (see ``_ca_live_mask``)."""
+    start = np.asarray(kv_start, np.int64)
+    length = np.asarray(kv_len, np.int64)
+    top = int(length.max(initial=0))
+    # cum[L, k]: live kv blocks j < k of a task whose kv range is L blocks
+    # long (row 0: an empty task slot)
+    cum = np.zeros((top + 1, top + 1), np.int64)
+    cum[1:, 1:] = np.cumsum(live_block_mask(mask, top, top, blk), axis=1)
+    fwd = cum[length, np.minimum(length, jmax)].sum(axis=-1)
+    dkv = cum[length, np.clip(n_kv - start, 0, length)].sum(axis=-1)
+    n_tasks = length.shape[-1]
+    return n_tasks * jmax, fwd, n_kv * n_tasks, dkv

@@ -11,9 +11,13 @@ without growing.
 
 Two timestamp sources coexist deliberately:
 
-  * host-side spans (plan build, prefetch, probes, serve rounds) are
-    measured with the recorder's injectable :class:`~repro.obs.clock.
-    Clock` (``span(...)`` context manager);
+  * host-side spans (plan build, prefetch, probes, serve rounds, the
+    trainer's batch fetch and step dispatch) are measured with the
+    recorder's injectable :class:`~repro.obs.clock.Clock`
+    (``span(...)`` context manager).  A live recorder also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name for the span's
+    extent, so every such span shows as a host event, on the thread
+    that ran it, in a profiler trace taken meanwhile;
   * step-execution spans carry **explicit** timestamps on a synthetic
     per-run timeline (``add_span``): the elastic executor lays each
     step's per-server serve/recovery intervals out in modeled or
@@ -79,8 +83,19 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, or None where
+    JAX is not installed (the recorder itself needs no JAX)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation(name)
+
+
 class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_track", "_step", "_args", "_t0")
+    __slots__ = ("_rec", "_name", "_track", "_step", "_args", "_t0",
+                 "_annotation")
 
     def __init__(self, rec: "TraceRecorder", name: str, track: str,
                  step: Optional[int], args: Optional[Dict[str, Any]]):
@@ -89,11 +104,16 @@ class _LiveSpan:
         self._step, self._args = step, args
 
     def __enter__(self):
+        self._annotation = _profiler_annotation(self._name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = self._rec.clock.monotonic()
         return self
 
     def __exit__(self, *exc):
         t1 = self._rec.clock.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._rec.add_span(self._name, self._track, self._t0,
                            t1 - self._t0, step=self._step,
                            args=self._args)
@@ -124,7 +144,8 @@ class TraceRecorder:
     # ------------------------------------------------------------ record
     def span(self, name: str, track: str, *, step: Optional[int] = None,
              args: Optional[Dict[str, Any]] = None):
-        """Context manager measuring a host-side span with the clock."""
+        """Context manager measuring a host-side span with the clock,
+        mirrored into the JAX profiler's trace under the same name."""
         if not self.enabled:
             return _NULL_SPAN
         return _LiveSpan(self, name, track, step, args)

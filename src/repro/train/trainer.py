@@ -15,6 +15,7 @@ from repro.cad import CADSession
 from repro.checkpoint import ckpt
 from repro.data.pipeline import PipelineConfig, raw_batches
 from repro.models import model as M
+from repro.obs import trace as obs_trace
 from repro.optim.adamw import AdamW, AdamWState, cosine_schedule
 from repro.parallel import ParallelContext, param_pspecs
 from repro.train.step import make_train_step
@@ -72,7 +73,13 @@ def train(cfg, pipe_cfg: PipelineConfig, train_cfg: TrainConfig,
     and batches are placed on it, and the step keeps them there.
 
     The result holds the final ``params`` and ``opt_state``, the logged
-    ``history`` and the jitted ``step_fn``."""
+    ``history`` and the jitted ``step_fn``.
+
+    Each step is narrated on the recorder's ``step`` track (DESIGN.md
+    §14): ``train.fetch`` waits for the next (planned) batch and
+    ``train.dispatch`` transfers it and launches the jitted step, with
+    the plan's CA-server grid counts as its args.  Both are no-ops
+    unless tracing is enabled."""
     faults = pool = None
     if session is not None:
         if train_cfg.fault_schedule:
@@ -142,10 +149,18 @@ def train(cfg, pipe_cfg: PipelineConfig, train_cfg: TrainConfig,
                     print(f"step {step:5d} pool: "
                           f"{', '.join(pool_events)} "
                           f"(epoch {pool.epoch})")
-            batch = next(gen)
+            # the recorder is looked up per span: tracing may be
+            # switched on while the loop runs
+            with obs_trace.get_recorder().span("train.fetch", "step",
+                                               step=step):
+                batch = next(gen)
             stats = batch.pop("schedule_stats", None)
             plan = batch.get("plan") if calibrating else None
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with obs_trace.get_recorder().span(
+                    "train.dispatch", "step", step=step,
+                    args=(stats or {}).get("grid")):
+                params, opt_state, metrics = step_fn(params, opt_state,
+                                                     batch)
             if calibrating and plan is not None \
                     and step % train_cfg.calibrate_every == 0:
                 # measure → fit: per-server kernel timings feed the
