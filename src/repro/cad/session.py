@@ -47,7 +47,8 @@ from repro.core.cost_model import (CalibrationSnapshot, CommModel,
 from repro.core.dispatch import CADContext, iter_plan_tasks, \
     probe_plan_times
 from repro.core.mask import MaskSpec, parse_mask, validate_mask_layout
-from repro.core.plan import CADConfig, PingPongPlan, StepPlan
+from repro.core.plan import (CADConfig, PingPongPlan, PlanCapacityError,
+                             StepPlan, ca_pair_bound)
 from repro.kernels.packed_flash.kernel import ca_grid_cells
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -57,22 +58,37 @@ Plan = Union[StepPlan, PingPongPlan]
 
 
 def grid_counts(plan: Plan, jmax: int, blk: int,
-                mask: Optional[MaskSpec] = None) -> Dict[str, int]:
+                mask: Optional[MaskSpec] = None,
+                max_pairs: Optional[int] = None) -> Dict[str, int]:
     """The CA-server grid cells a planned step launches, per head and
     layer, and how many run a body (``ca_grid_cells``), summed over
     ping-pong halves and servers: ``ca_fwd_cells[_live]`` for the forward
-    and dq grid (T tasks, jmax), ``ca_dkv_cells[_live]`` for the dk/dv
-    grid (N kv blocks, T)."""
+    and dq pair grid, ``ca_dkv_cells[_live]`` for the dk/dv pair grid,
+    with the pair lists sized by ``max_pairs`` (None: the dense T·jmax
+    and N·T).  Raises :class:`PlanCapacityError` for a server whose task
+    batch needs more list entries than that: the kernels would drop the
+    pairs past the end."""
     out = dict.fromkeys(("ca_fwd_cells", "ca_fwd_cells_live",
                          "ca_dkv_cells", "ca_dkv_cells_live"), 0)
-    for p in (list(plan) if isinstance(plan, PingPongPlan) else [plan]):
-        fwd, fwd_live, dkv, dkv_live = ca_grid_cells(
-            p["task_kv_start"], p["task_kv_len"],
-            np.asarray(p["kv_gather"]).shape[1], jmax, blk, mask)
-        out["ca_fwd_cells"] += fwd * fwd_live.size
-        out["ca_fwd_cells_live"] += int(fwd_live.sum())
-        out["ca_dkv_cells"] += dkv * dkv_live.size
-        out["ca_dkv_cells_live"] += int(dkv_live.sum())
+    halves = list(plan) if isinstance(plan, PingPongPlan) else [plan]
+    for half, p in enumerate(halves):
+        g = ca_grid_cells(p["task_kv_start"], p["task_kv_len"],
+                          np.asarray(p["kv_gather"]).shape[1], jmax, blk,
+                          mask, max_pairs)
+        for name, need, room in (("ca_fwd_pairs", g.fwd_need, g.fwd_room),
+                                 ("ca_dkv_pairs", g.dkv_need, g.dkv_room)):
+            over = np.flatnonzero(need > room)
+            if over.size:
+                s = int(over[0])
+                raise PlanCapacityError(
+                    name, None, None, int(need[s]), room, server=s,
+                    remedy=f"plan half {half}: its task ranges cover more "
+                    f"(task, kv block) pairs than documents of at most "
+                    f"jmax={jmax} blocks can")
+        out["ca_fwd_cells"] += g.fwd * g.fwd_live.size
+        out["ca_fwd_cells_live"] += int(g.fwd_live.sum())
+        out["ca_dkv_cells"] += g.dkv * g.dkv_live.size
+        out["ca_dkv_cells_live"] += int(g.dkv_live.sum())
     return out
 
 
@@ -341,7 +357,9 @@ class CADSession:
         halves — plans from ONE calibration snapshot, recorded in the
         stats as ``calib_version`` (+ the per-server speeds used).  The
         stats' ``grid`` counts the CA-server grid cells the step walks
-        (:func:`grid_counts`).
+        (:func:`grid_counts`), whose check raises
+        :class:`PlanCapacityError` for a plan over the Pallas kernels'
+        pair bound (``plan.ca_pair_bound``).
 
         Each call is narrated to the observability layer (DESIGN.md
         §14): a ``plan.build`` span on the ``planner`` track, tagged
@@ -352,8 +370,11 @@ class CADSession:
                                            args={"policy":
                                                  self.plan_policy}):
             plan, stats = self._plan_impl(segment_ids)
-        stats["grid"] = grid_counts(plan, self.jmax or self.cfg.nkv,
-                                    self.cfg.blk, self.mask)
+        jmax = self.jmax or self.cfg.nkv
+        stats["grid"] = grid_counts(
+            plan, jmax, self.cfg.blk, self.mask,
+            ca_pair_bound(self.cfg, jmax) if self.kernel == "pallas"
+            else None)
         if "pool_epoch" in stats:
             obs_metrics.get_registry().gauge(
                 "cad_pool_epoch", "pool membership epoch").set(

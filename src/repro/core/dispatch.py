@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.attention import NEG_INF, xla_flash_attention
 from repro.core.mask import live_block_mask, live_kv_len, mask_params
-from repro.core.plan import CADConfig, PingPongPlan
+from repro.core.plan import CADConfig, PingPongPlan, ca_pair_bound
 from repro.obs import server_track
 from repro.obs import trace as obs_trace
 
@@ -301,11 +301,14 @@ def _serve(q_tasks, qpos_tasks, k_buf, v_buf, kpos_buf, plan, cad,
     jmax = cad.jmax or cad.cfg.nkv
     window, sink, rate = mask_params(cad.mask, window)
     if cad.kernel == "pallas":
+        # pair lists sized by the pool's bound, which CADSession.plan
+        # checks every plan against
         from repro.kernels.packed_flash.ops import ca_server_attention
         return ca_server_attention(
             q_tasks, k_buf, v_buf, plan["task_kv_start"],
             plan["task_kv_len"], qpos_tasks, kpos_buf,
-            True, window, softcap, scale, jmax, cad.bwd, sink, rate)
+            True, window, softcap, scale, jmax, cad.bwd, sink, rate,
+            ca_pair_bound(cad.cfg, jmax))
     return _xla_server(q_tasks, k_buf, v_buf, plan["task_kv_start"],
                        plan["task_kv_len"], qpos_tasks, kpos_buf,
                        jmax, softcap, window, scale, sink, rate)
@@ -743,7 +746,7 @@ def _ring_serve_merge(cad: CADContext, inputs_s, pass_plans, server: int,
             jnp.asarray(pp["task_kv_start"][server]),
             jnp.asarray(pp["task_kv_len"][server]), qpos, kpos,
             max(pp["jmax"], 1), window, softcap, scale, sink, rate,
-            cad.kernel)
+            cad.kernel, ca_pair_bound(cad.cfg, cad.jmax or cad.cfg.nkv))
         merged = (o, l) if merged is None \
             else O.merge_softmax_partials(merged[0], merged[1], o, l)
     return merged[0]
@@ -807,6 +810,7 @@ def ring_global_sim(q, k, v, pos, plan, cad: CADContext,
     )(qb, kb, vb, posb, recv, plan)
 
     window, sink, rate = mask_params(cad.mask, 0)
+    bound = ca_pair_bound(cfg, cad.jmax or cfg.nkv)
     merged = None
     for t, pp in enumerate(pass_plans):
         if t > 0 and pp["jmax"] <= 0:
@@ -815,7 +819,7 @@ def ring_global_sim(q, k, v, pos, plan, cad: CADContext,
             lambda qt, kbf, vbf, st, ln, qp, kp, jm=max(pp["jmax"], 1):
             O.ca_partial_attention(qt, kbf, vbf, st, ln, qp, kp, jm,
                                    window, softcap, scale, sink, rate,
-                                   cad.kernel)
+                                   cad.kernel, bound)
         )(q_tasks, k_buf, v_buf, jnp.asarray(pp["task_kv_start"]),
           jnp.asarray(pp["task_kv_len"]), qpos_tasks, kpos_buf)
         merged = (o, l) if merged is None \

@@ -74,17 +74,23 @@ class PlanCapacityError(RuntimeError):
     ``python -O`` and reports which capacity broke and by how much.
     """
 
-    def __init__(self, capacity: str, src: int, dst: int, needed: int,
-                 available: int):
+    def __init__(self, capacity: str, src: Optional[int],
+                 dst: Optional[int], needed: int, available: int, *,
+                 server: Optional[int] = None,
+                 remedy: Optional[str] = None):
         self.capacity = capacity
         self.src = src
         self.dst = dst
+        self.server = server
         self.needed = needed
         self.available = available
+        where = f"(src={src}, dst={dst})" if server is None \
+            else f"server {server}"
+        remedy = remedy or (f"raise CADConfig.{capacity.lower()} or "
+                            f"loosen the schedule")
         super().__init__(
-            f"{capacity} capacity exceeded on (src={src}, dst={dst}): "
-            f"needed {needed} block slots, only {available} available "
-            f"(raise CADConfig.{capacity.lower()} or loosen the schedule)")
+            f"{capacity} capacity exceeded on {where}: needed {needed} "
+            f"slots, only {available} available ({remedy})")
 
 
 def _register_plan_dataclass(cls):
@@ -253,6 +259,23 @@ class CADConfig:
                    server_hbm=None if server_hbm is None
                    else tuple(server_hbm),
                    stream_chunk=int(stream_chunk))
+
+
+def ca_pair_bound(cfg: CADConfig, jmax: int) -> int:
+    """The most (task, kv block) pairs one server's CA-task batch covers,
+    which sizes the CA-server kernels' pair lists (``kernels/
+    packed_flash`` ``_list_lengths``) wherever the Pallas server runs a
+    plan of this pool (``dispatch._serve``, the ring passes).
+
+    Every q block of the D·nb in a (nano-)batch is one task whose kv
+    range is its causal prefix within its document, or a part of it
+    (a mask, a ring pass), and documents span at most ``jmax`` blocks.
+    A document of L blocks covers L(L+1)/2 pairs, at most L(jmax+1)/2,
+    so the whole pool covers at most D·nb·(jmax+1)/2, and no server more
+    than that or than its dense T·jmax.  ``CADSession.plan`` raises
+    :class:`PlanCapacityError` for a plan over it."""
+    whole_pool = cfg.n_servers * -(-cfg.nb * (jmax + 1) // 2)
+    return min(whole_pool, cfg.n_tasks * jmax)
 
 
 def empty_plan(cfg: CADConfig) -> Dict[str, np.ndarray]:

@@ -38,6 +38,7 @@ import jax
 
 from repro.core.cost_model import CalibrationSnapshot
 from repro.core.dispatch import CADContext, serve_task_batch
+from repro.core.plan import ca_pair_bound
 from repro.fabric.tenancy import (SERVE, TRAIN, AdmissionPolicy,
                                   AdmissionRound, ServeTaskReq,
                                   admit_serve)
@@ -107,6 +108,14 @@ class FabricExecutor(ElasticExecutor):
                                      kernel=session.kernel,
                                      bwd=session.bwd,
                                      jmax=workload.jmax)
+        # a fused serve batch covers one pair per kv block of its tasks,
+        # so batches are cut to the pair bound its lists are sized by
+        self._serve_pairs = ca_pair_bound(session.cfg, workload.jmax)
+        if self._serve_pairs < workload.jmax:
+            raise ValueError(
+                f"a request of {workload.jmax} kv blocks covers more "
+                f"pairs than the pool's CA-server bound "
+                f"{self._serve_pairs} (plan.ca_pair_bound)")
 
     # ---------------------------------------------------------- stepping
     def run_mixed_step(self, step: int, q, k, v, pos, segment_ids, *,
@@ -259,14 +268,13 @@ class FabricExecutor(ElasticExecutor):
 
     # ----------------------------------------------------------- serving
     def _run_serve(self, server: int, placed, snap, step: int) -> float:
-        """Execute one server's placed serve tasks (slot-sized fused
-        groups) and commit their outputs.  Returns the server's serve
-        seconds under the executor's timer."""
+        """Execute one server's placed serve tasks (fused groups,
+        ``ServeWorkload.groups``) and commit their outputs.  Returns the
+        server's serve seconds under the executor's timer."""
         slow = self.faults.slow_factor(step, server)
         secs = 0.0
         w = self.workload
-        for i in range(0, len(placed), w.slots):
-            group = placed[i:i + w.slots]
+        for group in w.groups(placed, self._serve_pairs):
             inputs, plan = w.build_batch(group)
             if self.timer == "wall":
                 t0 = self.clock.monotonic()

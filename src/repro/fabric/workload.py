@@ -144,6 +144,23 @@ class ServeWorkload:
             self.waits[t.rid] = self.waits.get(t.rid, 0) + 1
 
     # ---------------------------------------------------------- execution
+    def groups(self, tasks: Sequence[ServeTaskReq], max_pairs: int):
+        """``tasks`` in order as fused batches for ``build_batch``: at
+        most ``slots`` tasks, whose kv blocks (each task its own range)
+        sum to at most ``max_pairs``, the CA-server kernels' pair bound
+        (``plan.ca_pair_bound``)."""
+        group, pairs = [], 0
+        for t in tasks:
+            blocks = -(-t.kv_tokens // self.blk)
+            if group and (len(group) == self.slots
+                          or pairs + blocks > max_pairs):
+                yield group
+                group, pairs = [], 0
+            group.append(t)
+            pairs += blocks
+        if group:
+            yield group
+
     def build_batch(self, tasks: Sequence[ServeTaskReq]):
         """Fused inputs for up to ``slots`` tasks on one server:
         ``((q_tasks, qpos, k_buf, v_buf, kpos), plan)`` in

@@ -12,10 +12,16 @@ Forward kernels:
    as a second output — the residual the backward kernels need.
 
 2. ``ca_server_fwd`` — the attention-server kernel: a fused batch of
-   CA-tasks (q-block, kv-prefix-range), where the kv range of each task is
-   looked up through *scalar-prefetch* metadata (kv_start/kv_len), i.e.
-   data-dependent BlockSpec index maps.  This is the TPU-native analogue
-   of FA2 varlen batching that DistCA's attention servers rely on.
+   CA-tasks (q-block, kv-prefix-range).  Its grid (head, pair) walks a
+   *scalar-prefetched* list of the (task, kv block) pairs the tasks'
+   ranges (kv_start/kv_len) cover, task-major with kv blocks rising,
+   i.e. data-dependent BlockSpec index maps over only the work there is.
+   Each empty task slot gets one predicated-off cell, so its output is
+   still written; padding cells repeat the last pair's blocks (no DMA).
+   A list longer than ``LIST_CELLS`` (scalar memory) runs as several
+   calls, each over the runs of pairs that start in its stretch of the
+   list.  This is the TPU-native analogue of FA2 varlen batching that DistCA's
+   attention servers rely on.
 
 Backward kernels (flash-style, recompute-free: ``p`` is rebuilt from the
 saved ``(out, lse)`` residuals instead of a second online-softmax pass):
@@ -27,11 +33,13 @@ saved ``(out, lse)`` residuals instead of a second online-softmax pass):
    touches exactly the forward's (i, j) pairs.
 
 4. ``ca_server_bwd`` — the attention-server backward, honoring the same
-   per-task ``kv_start``/``kv_len`` scalar-prefetch layout: dq walks each
-   task's kv range; dk/dv inverts the mapping with a (kv-block, task)
-   grid whose body is predicated on "task t's range covers block n", a
-   scalar-prefetch condition — so servers run balanced bwd tasks in place
-   (paper §4 ping-pong symmetry between fwd and bwd tasks).
+   per-task ``kv_start``/``kv_len`` layout: dq walks the forward's pair
+   list; dk/dv inverts the mapping with a list of the (kv block, task)
+   pairs, kv-block major with tasks rising, one cell for each kv block
+   no range covers — so servers run balanced bwd tasks in place (paper
+   §4 ping-pong symmetry between fwd and bwd tasks).  Both passes keep
+   the forward's accumulation order, so any list length or split gives
+   the same bits; ``max_pairs`` (``_list_lengths``) sizes the lists.
 
 GQA note: the dk/dv passes emit per-*query*-head gradients; the jnp
 wrappers fold the repeat groups back onto kv heads.  That costs rep× the
@@ -55,6 +63,7 @@ for v5e ahead of time in tests/test_tpu_compile.py.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -593,24 +602,185 @@ def _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window, sink, rate,
                     rate, blk)
 
 
-def _ca_server_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
+# Scalar memory (SMEM) of a TPU v5e core is 1 MiB.  A pair list is two
+# int32 words a cell, row and column (one packed word, decoded in every
+# index map, cost smollm-360m training 1.4% of its tokens/s on a TPU
+# v5e); a list longer than this many cells runs as several calls
+# (``_chunks``), which leaves room for the task ranges.
+LIST_CELLS = 3 << 15
+
+
+def _list_lengths(n_tasks, n_kv, jmax, max_pairs):
+    """Entries the CA-server pair lists hold: ``(forward and dq, dk/dv)``.
+
+    Each list names the (task, kv block) pairs the task ranges cover,
+    plus one entry for each task slot (forward, dq) or kv slot (dk/dv)
+    that no pair names.  ``max_pairs`` bounds the covered pairs of a
+    batch, so the lists fit ``max_pairs + T`` and ``max_pairs + N``
+    entries; None (no bound) gives the dense sizes ``T·jmax`` and ``N·T``,
+    which always fit."""
+    dense_f, dense_d = n_tasks * jmax, n_kv * n_tasks
+    if max_pairs is None:
+        return dense_f, dense_d
+    return (min(max_pairs + n_tasks, dense_f),
+            min(max_pairs + n_kv, dense_d))
+
+
+def _chunking(n_cells, run_len):
+    """``(chunks, cells per chunk)`` a pair list of ``n_cells`` entries is
+    launched as, when no run of entries that share an output block is
+    longer than ``run_len``: one call up to ``LIST_CELLS``, else chunks
+    of ``LIST_CELLS`` cells, each holding the runs that start in its
+    stride of ``LIST_CELLS - run_len + 1`` entries."""
+    if n_cells <= LIST_CELLS:
+        return 1, n_cells
+    if run_len > LIST_CELLS:
+        raise ValueError(f"a run of {run_len} pairs does not fit the "
+                         f"{LIST_CELLS} cells of scalar memory")
+    return -(-n_cells // (LIST_CELLS - run_len + 1)), LIST_CELLS
+
+
+def ca_grid_lengths(n_tasks, n_kv, jmax, max_pairs=None):
+    """Cells per head of the CA-server pair grids as launched, over all
+    chunks: ``(P_f, P_d)`` for the forward and dq grid and for the dk/dv
+    grid (``_list_lengths``, ``_chunking``)."""
+    lf, ld = _list_lengths(n_tasks, n_kv, jmax, max_pairs)
+    cf, wf = _chunking(lf, jmax)
+    cd, wd = _chunking(ld, n_tasks)
+    return cf * wf, cd * wd
+
+
+def _pair_list(cover, n_cells):
+    """Row-major list of the True cells of ``cover`` [R, C], padded to
+    ``n_cells`` by repeating the last real entry (a padding cell maps to
+    the blocks already resident, so it moves no data).  Returns the
+    entries' rows and columns and a [1] array of the real entries; the
+    callers make every row hold at least one True cell."""
+    cols = cover.shape[1]
+    n_real = jnp.minimum(jnp.sum(cover, dtype=jnp.int32), n_cells)
+    (flat,) = jnp.nonzero(cover.reshape(-1), size=n_cells, fill_value=0)
+    flat = jnp.where(jnp.arange(n_cells) < n_real, flat,
+                     flat[n_real - 1]).astype(jnp.int32)
+    return flat // cols, flat % cols, n_real[None]
+
+
+def _task_pairs(kv_len, jmax, n_cells):
+    """(task, relative kv block) pairs of the forward and dq grids, task
+    major and j rising: j < min(kv_len, jmax), and (t, 0) for an empty
+    task slot (its body is predicated off, its outputs still written)."""
+    cover = jnp.arange(jmax)[None, :] < jnp.minimum(kv_len, jmax)[:, None]
+    return _pair_list(cover.at[:, 0].set(True), n_cells)
+
+
+def _kv_pairs(kv_start, kv_len, n_kv, n_cells):
+    """(kv block, task) pairs of the dk/dv grid, kv-block major and t
+    rising: task t's range covers block n, and (n, 0) for a kv slot no
+    task covers (predicated off; its dk/dv written as zeros)."""
+    jrel = jnp.arange(n_kv)[:, None] - kv_start[None, :]
+    cover = (jrel >= 0) & (jrel < kv_len[None, :])
+    cover = cover.at[:, 0].set(cover[:, 0] | ~cover.any(axis=1))
+    return _pair_list(cover, n_cells)
+
+
+def _chunks(rows, cols, n_real, run_len):
+    """Split a pair list into the lists of ``_chunking``: ``[(rows, cols,
+    n_real)]``.  Chunk c holds the runs (entries that share an output
+    block, a row) starting in its stride, padded like ``_pair_list``, so
+    every output block is accumulated and written by one call in the
+    list's order.  A chunk no run starts in redoes the last run: the same
+    cells write the same bits."""
+    n_chunks, width = _chunking(rows.shape[0], run_len)
+    if n_chunks == 1:
+        return [(rows, cols, n_real)]
+    stride = width - run_len + 1
+    n = n_real[0]
+    pos = jnp.arange(rows.shape[0], dtype=jnp.int32)
+    opens = (pos < n) & ((pos == 0) | (rows != jnp.roll(rows, 1)))
+    # the run starts in order, then n; a chunk opens at the first run
+    # start at or after its stride's first entry
+    (starts,) = jnp.nonzero(opens, size=pos.size + 1, fill_value=n)
+    edges = starts[jnp.minimum(jnp.searchsorted(
+        starts, jnp.asarray(np.arange(n_chunks + 1) * stride, jnp.int32)),
+        pos.size)]
+    last_run = starts[jnp.sum(opens, dtype=jnp.int32) - 1]
+    out = []
+    for c in range(n_chunks):
+        lo, hi = edges[c], edges[c + 1]
+        lo, hi = (jnp.where(hi > lo, lo, last_run),
+                  jnp.where(hi > lo, hi, n))
+        parts = []
+        for x in (rows, cols):
+            part = jax.lax.dynamic_slice(
+                jnp.concatenate([x, jnp.full((width,), x[-1])]), (lo,),
+                (width,))
+            parts.append(jnp.where(jnp.arange(width) < hi - lo, part,
+                                   part[hi - lo - 1]))
+        out.append((*parts, (hi - lo)[None]))
+    return out
+
+
+def _pair_call(kernel, chunks, scalars, operands, in_specs, out_specs,
+               out_shape, scratch, n_heads, interpret):
+    """Run a CA-server pair kernel over each chunk's list: grid (head,
+    cell), scalar prefetch ``(rows, cols, n_real, *scalars)``.  Each call
+    after the first takes the previous call's outputs, aliased, and
+    writes only the output blocks of its own runs into them."""
+    n_in = 3 + len(scalars) + len(operands)
+    res = None
+    for rows, cols, n_real in chunks:
+        prev = () if res is None else jax.tree.leaves(res)
+        body = kernel
+        if prev:
+            def body(*refs, n=len(prev)):        # drop the aliased refs
+                return kernel(*refs[:n_in], *refs[n_in + n:])
+        res = pl.pallas_call(
+            body,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3 + len(scalars),
+                grid=(n_heads, rows.shape[0]),
+                in_specs=list(in_specs)
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(prev),
+                out_specs=out_specs,
+                scratch_shapes=scratch,
+            ),
+            out_shape=out_shape,
+            input_output_aliases={n_in + i: i for i in range(len(prev))},
+            compiler_params=_params("parallel", "arbitrary"),
+            interpret=interpret,
+        )(rows, cols, n_real, *scalars, *operands, *prev)
+    return res
+
+
+def _run_edges(owner_ref, p):
+    """Whether cell ``p`` opens and whether it closes the run of cells
+    that share its output block (``owner_ref[p]``)."""
+    n_cells = pl.num_programs(1)
+    own = owner_ref[p]
+    first = (p == 0) | (owner_ref[jnp.maximum(p - 1, 0)] != own)
+    last = (p == n_cells - 1) | \
+        (owner_ref[jnp.minimum(p + 1, n_cells - 1)] != own)
+    return first, last
+
+
+def _ca_server_kernel(tt_ref, jj_ref, n_real_ref, kv_start_ref,
+                      kv_len_ref,                      # scalar prefetch
                       q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref, *rest,
                       scale, softcap, causal, window, sink, rate, blk,
-                      jmax, save_lse=False):
+                      save_lse=False):
     if save_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         (o_ref, m_scr, l_scr, acc_scr), lse_ref = rest, None
-    t = pl.program_id(0)
-    j = pl.program_id(2)
+    p = pl.program_id(1)
+    first, last = _run_edges(tt_ref, p)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         _softmax_init(m_scr, l_scr, acc_scr)
 
     mask, any_live = _ca_live_mask(q_pos_ref, kv_pos_ref, causal, window,
                                    sink, rate, blk)
-    live = j < kv_len_ref[t]
+    live = (p < n_real_ref[0]) & (jj_ref[p] < kv_len_ref[tt_ref[p]])
     if mask is not None:
         live &= any_live
 
@@ -624,55 +794,65 @@ def _ca_server_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
         logits = _capped_masked_logits(q, k, m, scale, softcap)
         _softmax_update(logits, m, v, m_scr, l_scr, acc_scr)
 
-    @pl.when(j == jmax - 1)
+    @pl.when(last)
     def _finalize():
         _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
 def _ca_specs(blk, dh, rep, n_kv_blocks):
     """BlockSpecs of the CA-server operands over the heads-major layout,
-    for the (task, head, relative kv block) grid with the per-task kv
-    ranges in scalar prefetch."""
-    def kv_block(t, j, starts):
-        return jnp.minimum(starts[t] + j, n_kv_blocks - 1)
+    for the (head, pair) grid of the forward and dq passes: cell p is
+    task ``tt[p]`` against its relative kv block ``jj[p]``, the task's kv
+    range in scalar prefetch."""
+    def kv_block(p, tt, jj, starts):
+        return jnp.minimum(starts[tt[p]] + jj[p], n_kv_blocks - 1)
 
     return dict(
         q_pos=pl.BlockSpec((None, blk, 1),
-                           lambda t, h, j, st, ln: (t, 0, 0)),
+                           lambda h, p, tt, jj, nr, st, ln: (tt[p], 0, 0)),
         kv_pos=pl.BlockSpec((None, 1, blk),
-                            lambda t, h, j, st, ln: (kv_block(t, j, st),
-                                                     0, 0)),
+                            lambda h, p, tt, jj, nr, st, ln:
+                            (kv_block(p, tt, jj, st), 0, 0)),
         q=pl.BlockSpec((None, None, blk, dh),
-                       lambda t, h, j, st, ln: (t, h, 0, 0)),
+                       lambda h, p, tt, jj, nr, st, ln: (tt[p], h, 0, 0)),
         kv=pl.BlockSpec((None, None, blk, dh),
-                        lambda t, h, j, st, ln: (kv_block(t, j, st),
-                                                 h // rep, 0, 0)),
+                        lambda h, p, tt, jj, nr, st, ln:
+                        (kv_block(p, tt, jj, st), h // rep, 0, 0)),
         row=pl.BlockSpec((None, None, blk, 1),
-                         lambda t, h, j, st, ln: (t, h, 0, 0)),
+                         lambda h, p, tt, jj, nr, st, ln: (tt[p], h, 0, 0)),
     )
 
 
 def ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, *,
                   causal=True, window=0, sink=0, rate=1, softcap=0.0,
-                  scale=None, jmax=None, interpret=True, return_lse=False):
+                  scale=None, jmax=None, max_pairs=None, interpret=True,
+                  return_lse=False):
     """Fused CA-task batch (see ref.ref_ca_server_attention for semantics).
 
     q_tasks [T,blk,Hq,dh]; k_buf/v_buf [N,blk,Hkv,dh]; kv_start/kv_len [T];
     q_pos [T,blk]; kv_pos [N,blk].  ``jmax`` bounds the kv blocks any task
-    may touch (defaults to N).  window/sink/rate are the MaskSpec terms
-    (DESIGN.md §12); kv blocks of a task's prefix that the mask leaves
-    fully dead are skipped via ``_ca_live_mask``'s exact predicate.
-    With ``return_lse`` the lse [T, Hq, blk] float32 comes second."""
+    may touch (defaults to N).  The grid walks only the (task, kv block)
+    pairs the ranges cover (``_task_pairs``); ``max_pairs`` bounds them
+    per batch and sizes the list (``_list_lengths``; None: dense), which
+    runs as one call or, past scalar memory, as several (``_chunks``).  A
+    batch with more pairs than that is truncated, so callers guarantee
+    the bound (``plan.ca_pair_bound``; ``CADSession.plan`` raises
+    ``PlanCapacityError`` for a plan over it).  window/sink/rate are the
+    MaskSpec terms (DESIGN.md §12); kv blocks of a task's prefix that the
+    mask leaves fully dead are skipped via ``_ca_live_mask``'s exact
+    predicate.  With ``return_lse`` the lse [T, Hq, blk] float32 comes
+    second."""
     T, blk, hq, dh = q_tasks.shape
     N, _, hkv, _ = k_buf.shape
     rep = hq // hkv
     scale = scale if scale is not None else dh ** -0.5
     jmax = jmax or N
+    n_cells = _list_lengths(T, N, jmax, max_pairs)[0]
     sp = _ca_specs(blk, dh, rep, N)
 
     kernel = functools.partial(
         _ca_server_kernel, scale=scale, softcap=softcap, causal=causal,
-        window=window, sink=sink, rate=rate, blk=blk, jmax=jmax,
+        window=window, sink=sink, rate=rate, blk=blk,
         save_lse=return_lse)
     out_shape = jax.ShapeDtypeStruct((T, hq, blk, dh), q_tasks.dtype)
     out_specs = sp["q"]
@@ -680,21 +860,13 @@ def ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, *,
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((T, hq, blk, 1), jnp.float32))
         out_specs = (out_specs, sp["row"])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, hq, jmax),
-        in_specs=[sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"], sp["kv"]],
-        out_specs=out_specs,
-        scratch_shapes=_softmax_scratch(blk, dh),
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-    )(kv_start, kv_len, _col(q_pos), _row(kv_pos), _heads_major(q_tasks),
-      _heads_major(k_buf), _heads_major(v_buf))
+    res = _pair_call(
+        kernel, _chunks(*_task_pairs(kv_len, jmax, n_cells), jmax),
+        (kv_start, kv_len),
+        (_col(q_pos), _row(kv_pos), _heads_major(q_tasks),
+         _heads_major(k_buf), _heads_major(v_buf)),
+        [sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"], sp["kv"]],
+        out_specs, out_shape, _softmax_scratch(blk, dh), hq, interpret)
     if return_lse:
         out, lse = res
         return _heads_major(out), lse[..., 0]
@@ -702,21 +874,21 @@ def ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, *,
 
 
 # --------------------------------------------------- CA-server backward
-def _ca_bwd_dq_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
+def _ca_bwd_dq_kernel(tt_ref, jj_ref, n_real_ref, kv_start_ref,
+                      kv_len_ref,                      # scalar prefetch
                       q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dq_scr, *,
-                      scale, softcap, causal, window, sink, rate, blk,
-                      jmax):
-    t = pl.program_id(0)
-    j = pl.program_id(2)
+                      scale, softcap, causal, window, sink, rate, blk):
+    p = pl.program_id(1)
+    first, last = _run_edges(tt_ref, p)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     mask, any_live = _ca_live_mask(q_pos_ref, kv_pos_ref, causal, window,
                                    sink, rate, blk)
-    live = j < kv_len_ref[t]
+    live = (p < n_real_ref[0]) & (jj_ref[p] < kv_len_ref[tt_ref[p]])
     if mask is not None:
         live &= any_live
 
@@ -729,36 +901,36 @@ def _ca_bwd_dq_kernel(kv_start_ref, kv_len_ref,       # scalar prefetch
         m = _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window,
                           sink, rate, blk)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
+        probs = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
+        ds = _ds_from_p(probs, dp, delta_ref[...], logits, m, scale, softcap)
         dq_scr[...] += _mxu_dot(ds, k)
 
-    @pl.when(j == jmax - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _ca_bwd_dkv_kernel(kv_start_ref, kv_len_ref,      # scalar prefetch
+def _ca_bwd_dkv_kernel(nn_ref, tt_ref, n_real_ref, kv_start_ref,
+                       kv_len_ref,                     # scalar prefetch
                        q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref, do_ref,
                        lse_ref, delta_ref, dk_ref, dv_ref,
                        dk_scr, dv_scr, *,
-                       scale, softcap, causal, window, sink, rate, blk,
-                       n_tasks):
-    n = pl.program_id(0)
-    t = pl.program_id(2)
+                       scale, softcap, causal, window, sink, rate, blk):
+    p = pl.program_id(1)
+    first, last = _run_edges(nn_ref, p)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     # task t touches kv block n iff its prefix range covers it AND the
-    # mask keeps any (q, kv) pair of the block live — untouched
-    # (block, task) pairs skip the whole body (the bwd analogue of the
-    # fwd's mask-driven live-block iteration)
-    jrel = n - kv_start_ref[t]
-    covers = (jrel >= 0) & (jrel < kv_len_ref[t])
+    # mask keeps any (q, kv) pair of the block live — the cells of
+    # uncovered kv slots and the padding cells skip the whole body
+    t = tt_ref[p]
+    jrel = nn_ref[p] - kv_start_ref[t]
+    covers = (p < n_real_ref[0]) & (jrel >= 0) & (jrel < kv_len_ref[t])
     mask, any_live = _ca_live_mask(q_pos_ref, kv_pos_ref, causal, window,
                                    sink, rate, blk)
     if mask is not None:
@@ -773,13 +945,13 @@ def _ca_bwd_dkv_kernel(kv_start_ref, kv_len_ref,      # scalar prefetch
         m = _ca_task_mask(mask, q_pos_ref, kv_pos_ref, causal, window,
                           sink, rate, blk)
         logits = _capped_masked_logits(q, k, m, scale, softcap)
-        p = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
-        dv_scr[...] += _mxu_dot(p.T, do)
+        probs = jnp.where(m, jnp.exp(logits - lse_ref[...]), 0.0)
+        dv_scr[...] += _mxu_dot(probs.T, do)
         dp = _mxu_dot(do, v.T)
-        ds = _ds_from_p(p, dp, delta_ref[...], logits, m, scale, softcap)
+        ds = _ds_from_p(probs, dp, delta_ref[...], logits, m, scale, softcap)
         dk_scr[...] += _mxu_dot(ds.T, q)
 
-    @pl.when(t == n_tasks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[...] = dk_scr[...]
         dv_ref[...] = dv_scr[...]
@@ -788,109 +960,152 @@ def _ca_bwd_dkv_kernel(kv_start_ref, kv_len_ref,      # scalar prefetch
 def ca_server_bwd(q_tasks, k_buf, v_buf, out, lse, do, kv_start, kv_len,
                   q_pos, kv_pos, *, causal=True, window=0, sink=0,
                   rate=1, softcap=0.0, scale=None, jmax=None,
-                  interpret=True):
+                  max_pairs=None, interpret=True):
     """Hand-written backward for ``ca_server_fwd`` from saved (out, lse).
 
-    dq walks each task's kv prefix range exactly like the forward (same
-    scalar-prefetch index maps).  dk/dv inverts the task→kv-range mapping
-    with an (kv-block, head, task) grid predicated on range coverage, so
-    every kv block accumulates only the tasks whose prefix contains it."""
+    dq walks the forward's (task, kv block) pair list with the same
+    index maps.  dk/dv inverts the task→kv-range mapping with a list of
+    (kv block, task) pairs, kv-block major (``_kv_pairs``), so every kv
+    block accumulates only the tasks whose prefix contains it, in task
+    order.  ``max_pairs`` sizes both lists as in ``ca_server_fwd``."""
     T, blk, hq, dh = q_tasks.shape
     N, _, hkv, _ = k_buf.shape
     rep = hq // hkv
     scale = scale if scale is not None else dh ** -0.5
     jmax = jmax or N
+    cells_f, cells_d = _list_lengths(T, N, jmax, max_pairs)
 
     delta = jnp.einsum("tqhd,tqhd->thq", do.astype(jnp.float32),
                        out.astype(jnp.float32))
-    args = (kv_start, kv_len, _col(q_pos), _row(kv_pos),
-            _heads_major(q_tasks), _heads_major(k_buf), _heads_major(v_buf),
-            _heads_major(do), _col(lse), _col(delta))
+    args = (_col(q_pos), _row(kv_pos), _heads_major(q_tasks),
+            _heads_major(k_buf), _heads_major(v_buf), _heads_major(do),
+            _col(lse), _col(delta))
     mask_kw = dict(scale=scale, softcap=softcap, causal=causal,
                    window=window, sink=sink, rate=rate, blk=blk)
-    semantics = _params("parallel", "parallel", "arbitrary")
+    f32_block = [pltpu.VMEM((blk, dh), jnp.float32)]
 
     sp = _ca_specs(blk, dh, rep, N)
-    dq = pl.pallas_call(
-        functools.partial(_ca_bwd_dq_kernel, jmax=jmax, **mask_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(T, hq, jmax),
-            in_specs=[sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"],
-                      sp["kv"], sp["q"], sp["row"], sp["row"]],
-            out_specs=sp["q"],
-            scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, hq, blk, dh), q_tasks.dtype),
-        compiler_params=semantics,
-        interpret=interpret,
-    )(*args)
+    dq = _pair_call(
+        functools.partial(_ca_bwd_dq_kernel, **mask_kw),
+        _chunks(*_task_pairs(kv_len, jmax, cells_f), jmax),
+        (kv_start, kv_len), args,
+        [sp["q_pos"], sp["kv_pos"], sp["q"], sp["kv"], sp["kv"], sp["q"],
+         sp["row"], sp["row"]],
+        sp["q"], jax.ShapeDtypeStruct((T, hq, blk, dh), q_tasks.dtype),
+        f32_block, hq, interpret)
 
-    # dk/dv pass: grid (kv block n, head, task t), tasks innermost
-    def task(n, h, t, st, ln):
-        return (t, h, 0, 0)
+    # dk/dv pass: grid (head, pair), cell p is kv block nn[p] against
+    # task tt[p]
+    def task(h, p, nn, tt, nr, st, ln):
+        return (tt[p], h, 0, 0)
 
-    def kv_block(n, h, t, st, ln):
-        return (n, h // rep, 0, 0)
+    def kv_block(h, p, nn, tt, nr, st, ln):
+        return (nn[p], h // rep, 0, 0)
 
-    def kv_block_q_head(n, h, t, st, ln):
-        return (n, h, 0, 0)
+    def kv_block_q_head(h, p, nn, tt, nr, st, ln):
+        return (nn[p], h, 0, 0)
 
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_ca_bwd_dkv_kernel, n_tasks=T, **mask_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(N, hq, T),
-            in_specs=[
-                pl.BlockSpec((None, blk, 1),
-                             lambda n, h, t, st, ln: (t, 0, 0)),
-                pl.BlockSpec((None, 1, blk),
-                             lambda n, h, t, st, ln: (n, 0, 0)),
-                pl.BlockSpec((None, None, blk, dh), task),
-                pl.BlockSpec((None, None, blk, dh), kv_block),
-                pl.BlockSpec((None, None, blk, dh), kv_block),
-                pl.BlockSpec((None, None, blk, dh), task),
-                pl.BlockSpec((None, None, blk, 1), task),
-                pl.BlockSpec((None, None, blk, 1), task),
-            ],
-            out_specs=(pl.BlockSpec((None, None, blk, dh), kv_block_q_head),
-                       pl.BlockSpec((None, None, blk, dh), kv_block_q_head)),
-            scratch_shapes=[pltpu.VMEM((blk, dh), jnp.float32),
-                            pltpu.VMEM((blk, dh), jnp.float32)],
-        ),
-        out_shape=(jax.ShapeDtypeStruct((N, hq, blk, dh), jnp.float32),
-                   jax.ShapeDtypeStruct((N, hq, blk, dh), jnp.float32)),
-        compiler_params=semantics,
-        interpret=interpret,
-    )(*args)
+    grad = jax.ShapeDtypeStruct((N, hq, blk, dh), jnp.float32)
+    dk_h, dv_h = _pair_call(
+        functools.partial(_ca_bwd_dkv_kernel, **mask_kw),
+        _chunks(*_kv_pairs(kv_start, kv_len, N, cells_d), T),
+        (kv_start, kv_len), args,
+        [pl.BlockSpec((None, blk, 1),
+                      lambda h, p, nn, tt, nr, st, ln: (tt[p], 0, 0)),
+         pl.BlockSpec((None, 1, blk),
+                      lambda h, p, nn, tt, nr, st, ln: (nn[p], 0, 0)),
+         pl.BlockSpec((None, None, blk, dh), task),
+         pl.BlockSpec((None, None, blk, dh), kv_block),
+         pl.BlockSpec((None, None, blk, dh), kv_block),
+         pl.BlockSpec((None, None, blk, dh), task),
+         pl.BlockSpec((None, None, blk, 1), task),
+         pl.BlockSpec((None, None, blk, 1), task)],
+        (pl.BlockSpec((None, None, blk, dh), kv_block_q_head),
+         pl.BlockSpec((None, None, blk, dh), kv_block_q_head)),
+        (grad, grad), f32_block * 2, hq, interpret)
     return (_heads_major(dq), _fold_gqa(dk_h, hkv, k_buf.dtype),
             _fold_gqa(dv_h, hkv, v_buf.dtype))
 
 
-def ca_grid_cells(kv_start, kv_len, n_kv, jmax, blk, mask=None):
+class GridCells(NamedTuple):
+    """What ``ca_grid_cells`` counts per head for one task batch: ints for
+    the whole grid or list, [...] arrays (one per server) otherwise."""
+    fwd: int                  # cells the forward and dq grids launch
+    fwd_live: np.ndarray      # of them, the cells that run a body
+    dkv: int                  # cells the dk/dv grid launches
+    dkv_live: np.ndarray
+    fwd_need: np.ndarray      # entries the forward list needs ...
+    dkv_need: np.ndarray      # ... and the dk/dv list
+    fwd_room: int             # entries the lists hold (``_list_lengths``)
+    dkv_room: int
+
+
+def ca_grid_cells(kv_start, kv_len, n_kv, jmax, blk, mask=None,
+                  max_pairs=None):
     """Cells of the grids ``ca_server_fwd`` and ``ca_server_bwd`` launch
-    per head for one task batch, and how many of them run a body; host
-    numpy, for counting plans (``cad/session.py``).
+    per head for one task batch, how many of them run a body, and whether
+    the batch fits their pair lists; host numpy, for counting and
+    checking plans (``cad/session.py``).
 
     ``kv_start``/``kv_len`` are [..., T] (leading axes: servers) and
-    ``n_kv`` is the kv blocks each server holds.  Returns ``(fwd_cells,
-    fwd_live, dkv_cells, dkv_live)``: the size of the forward and dq grid
-    (T, jmax) and of the dk/dv grid (N, T), and [...] arrays of their live
-    cells.  Cell (t, j) is live when ``j < kv_len[t]``; cell (n, t) when
-    ``n - kv_start[t]`` lies in ``[0, kv_len[t])``.  With a ``MaskSpec``
-    a live cell must also hold a kv block that ``core.mask``'s
-    ``live_block_mask`` keeps for the task's q block (in-document index
-    ``kv_len - 1``); the kernels test the tokens themselves, so at a
-    sliding window's edge this can count one block per task that they
-    skip (see ``_ca_live_mask``)."""
+    ``n_kv`` is the kv blocks each server holds; ``max_pairs`` sizes the
+    lists as in the kernels.  Pair (t, j) is live when ``j < min(kv_len[t],
+    jmax)``; pair (n, t) when ``n - kv_start[t]`` lies in ``[0,
+    kv_len[t])``.  With a ``MaskSpec`` a live cell must also hold a kv
+    block that ``core.mask``'s ``live_block_mask`` keeps for the task's q
+    block (in-document index ``kv_len - 1``); the kernels test the tokens
+    themselves, so at a sliding window's edge this can count one block
+    per task that they skip (see ``_ca_live_mask``).  A list needs one
+    entry per covered pair and one per task slot (forward) or kv slot
+    (dk/dv) that no pair names, whatever the mask."""
     start = np.asarray(kv_start, np.int64)
     length = np.asarray(kv_len, np.int64)
+    n_tasks = length.shape[-1]
     top = int(length.max(initial=0))
     # cum[L, k]: live kv blocks j < k of a task whose kv range is L blocks
     # long (row 0: an empty task slot)
     cum = np.zeros((top + 1, top + 1), np.int64)
     cum[1:, 1:] = np.cumsum(live_block_mask(mask, top, top, blk), axis=1)
-    fwd = cum[length, np.minimum(length, jmax)].sum(axis=-1)
+    fwd = cum[length, np.minimum(length, jmax)]                # [..., T]
     dkv = cum[length, np.clip(n_kv - start, 0, length)].sum(axis=-1)
-    n_tasks = length.shape[-1]
-    return n_tasks * jmax, fwd, n_kv * n_tasks, dkv
+    # tasks covering each kv block: +1 where a range opens, -1 past it
+    lo = np.clip(start, 0, n_kv).reshape(-1, n_tasks)
+    hi = np.maximum(np.clip(start + length, 0, n_kv).reshape(lo.shape), lo)
+    steps = np.zeros((lo.shape[0], n_kv + 1), np.int64)
+    rows = np.arange(lo.shape[0])[:, None]
+    np.add.at(steps, (rows, lo), 1)
+    np.add.at(steps, (rows, hi), -1)
+    cover = np.cumsum(steps[:, :-1], axis=1)                   # [S, N]
+    runs_f = np.maximum(np.minimum(length, jmax), 1)
+    runs_d = np.maximum(cover, 1)
+    # live cells of the dk/dv list's last run, kv block N - 1
+    jrel = np.clip(n_kv - 1 - start, 0, length)
+    last_d = np.where((start < n_kv) & (n_kv - 1 - start < length),
+                      cum[length, np.minimum(jrel + 1, length)]
+                      - cum[length, jrel], 0).sum(axis=-1)
+    room_f, room_d = _list_lengths(n_tasks, n_kv, jmax, max_pairs)
+    cells_f, cells_d = ca_grid_lengths(n_tasks, n_kv, jmax, max_pairs)
+    lead = length.shape[:-1]
+    return GridCells(
+        cells_f, fwd.sum(axis=-1) + _redone(
+            runs_f.reshape(-1, n_tasks), fwd[..., -1].reshape(-1),
+            room_f, jmax).reshape(lead),
+        cells_d, dkv + _redone(runs_d, last_d.reshape(-1), room_d,
+                               n_tasks).reshape(lead),
+        runs_f.sum(axis=-1), runs_d.sum(axis=-1).reshape(lead),
+        room_f, room_d)
+
+
+def _redone(runs, last_live, n_cells, run_len):
+    """[S] cells with a body that a chunked launch runs a second time:
+    each chunk no run starts in redoes the list's last run (``_chunks``).
+    ``runs`` [S, R] are the list's run lengths in order, ``last_live``
+    [S] the cells of its last run that run a body."""
+    n_chunks, width = _chunking(n_cells, run_len)
+    if n_chunks == 1:
+        return np.zeros(runs.shape[0], np.int64)
+    starts = np.cumsum(runs, axis=-1) - runs
+    owned = np.zeros((runs.shape[0], n_chunks), bool)
+    owned[np.arange(runs.shape[0])[:, None],
+          np.minimum(starts // (width - run_len + 1), n_chunks - 1)] = True
+    return (n_chunks - owned.sum(axis=-1)) * last_live

@@ -80,41 +80,47 @@ packed_flash_attention.defvjp(_pf_fwd, _pf_bwd)
 
 # -------------------------------------------------------------- CA server
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
 def ca_server_attention(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                         kv_pos, causal=True, window=0, softcap=0.0,
-                        scale=None, jmax=0, bwd_impl=None, sink=0, rate=1):
+                        scale=None, jmax=0, bwd_impl=None, sink=0, rate=1,
+                        max_pairs=0):
     """Fused CA-task batch on an attention server (paper §4.1).
 
     ``jmax`` bounds the kv blocks any task may touch (0 -> all of k_buf);
     the scheduler's plan guarantees every ``kv_len`` fits under it.
-    ``sink``/``rate`` carry a non-causal MaskSpec (DESIGN.md §12)."""
+    ``sink``/``rate`` carry a non-causal MaskSpec (DESIGN.md §12).
+    ``max_pairs`` bounds the (task, kv block) pairs the ranges cover and
+    sizes the Pallas pair grids (0 -> the dense sizes)."""
     return K.ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                            kv_pos, causal=causal, window=window, sink=sink,
                            rate=rate, softcap=softcap, scale=scale,
-                           jmax=jmax or None, interpret=not _on_tpu())
+                           jmax=jmax or None, max_pairs=max_pairs or None,
+                           interpret=not _on_tpu())
 
 
 def _ca_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos,
-            causal, window, softcap, scale, jmax, bwd_impl, sink, rate):
+            causal, window, softcap, scale, jmax, bwd_impl, sink, rate,
+            max_pairs):
     out, lse = K.ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len,
                                q_pos, kv_pos, causal=causal, window=window,
                                sink=sink, rate=rate, softcap=softcap,
                                scale=scale, jmax=jmax or None,
+                               max_pairs=max_pairs or None,
                                interpret=not _on_tpu(), return_lse=True)
     return out, (q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos,
                  out, lse)
 
 
 def _ca_bwd(causal, window, softcap, scale, jmax, bwd_impl, sink, rate,
-            res, g):
+            max_pairs, res, g):
     q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos, kv_pos, out, lse = res
     if _resolve_bwd(bwd_impl) == "pallas":
         dq, dk, dv = K.ca_server_bwd(
             q_tasks, k_buf, v_buf, out, lse, g, kv_start, kv_len, q_pos,
             kv_pos, causal=causal, window=window, sink=sink, rate=rate,
             softcap=softcap, scale=scale, jmax=jmax or None,
-            interpret=not _on_tpu())
+            max_pairs=max_pairs or None, interpret=not _on_tpu())
         return dq, dk, dv, None, None, None, None
     if causal:
         # blockwise-jnp recompute fallback — the attention-server scan
@@ -224,10 +230,11 @@ merge_softmax_partials.defvjp(
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
 def ca_partial_attention(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                          kv_pos, jmax=0, window=0, softcap=0.0,
-                         scale=None, sink=0, rate=1, kernel="xla"):
+                         scale=None, sink=0, rate=1, kernel="xla",
+                         max_pairs=0):
     """One ring pass of a fused CA-task batch: attention over the pass's
     kv sub-range ``[kv_start, kv_start + kv_len)`` returning the
     finalized ``(out, lse)`` partial — both differentiable, so
@@ -236,20 +243,22 @@ def ca_partial_attention(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
     ``kernel.LSE_DEAD`` lse) that merges as a bitwise no-op.  ``kernel``
     picks the forward ("pallas" fused kernel / "xla" blockwise scan);
     backward always runs the blockwise recompute extended with the lse
-    cotangent (``ds = p * (dp - delta + g_lse)``)."""
+    cotangent (``ds = p * (dp - delta + g_lse)``).  ``max_pairs`` sizes
+    the Pallas pair list as in :func:`ca_server_attention`."""
     return _partial_fwd_impl(q_tasks, k_buf, v_buf, kv_start, kv_len,
                              q_pos, kv_pos, jmax, window, softcap, scale,
-                             sink, rate, kernel)
+                             sink, rate, kernel, max_pairs)
 
 
 def _partial_fwd_impl(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                       kv_pos, jmax, window, softcap, scale, sink, rate,
-                      kernel):
+                      kernel, max_pairs):
     if kernel == "pallas":
         return K.ca_server_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len,
                                q_pos, kv_pos, causal=True, window=window,
                                sink=sink, rate=rate, softcap=softcap,
                                scale=scale, jmax=jmax or None,
+                               max_pairs=max_pairs or None,
                                interpret=not _on_tpu(), return_lse=True)
     from repro.core import dispatch as D
     return D._xla_server_fwd_impl(q_tasks, k_buf, v_buf, kv_start, kv_len,
@@ -260,16 +269,16 @@ def _partial_fwd_impl(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
 
 def _ca_partial_fwd(q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                     kv_pos, jmax, window, softcap, scale, sink, rate,
-                    kernel):
+                    kernel, max_pairs):
     out, lse = _partial_fwd_impl(q_tasks, k_buf, v_buf, kv_start, kv_len,
                                  q_pos, kv_pos, jmax, window, softcap,
-                                 scale, sink, rate, kernel)
+                                 scale, sink, rate, kernel, max_pairs)
     return (out, lse), (q_tasks, k_buf, v_buf, kv_start, kv_len, q_pos,
                         kv_pos, out, lse)
 
 
 def _ca_partial_bwd(jmax, window, softcap, scale, sink, rate, kernel,
-                    res, g):
+                    max_pairs, res, g):
     g_out, g_lse = g
     from repro.core import dispatch as D
     dq, dk, dv = D._xla_server_bwd_impl(

@@ -15,6 +15,8 @@ from repro.core import (CADConfig, CADContext, CommModel, cad_attention,
                         identity_plan, imbalance, per_document_cp_plan,
                         plan_from_schedule, ref_attention, schedule)
 from repro.core.dispatch import _global_sim
+from repro.core.plan import ca_pair_bound
+from repro.kernels.packed_flash import kernel as K
 from repro.parallel import ParallelContext, ShardingRules
 
 BLK = 64
@@ -139,7 +141,11 @@ def test_dispatch_equivalence_fixed_plans(plan_fn):
                                atol=2e-5)
 
 
-def test_dispatch_pallas_server():
+@pytest.mark.parametrize("list_cells", [None, 64])
+def test_dispatch_pallas_server(list_cells, monkeypatch):
+    """The Pallas servers of the vmapped pool; with scalar memory for
+    ``list_cells`` pairs their lists run as several calls, which gives
+    the same bits."""
     rng = np.random.default_rng(5)
     d, s, hq, hkv, dh = 2, 6 * BLK, 2, 1, 64
     segs, poss = random_layout(rng, d, s)
@@ -160,6 +166,13 @@ def test_dispatch_pallas_server():
     out = cad_attention(q, k, v, seg, pos, seg, pos, ctx=ctx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=2e-5)
+    if list_cells:
+        monkeypatch.setattr(K, "LIST_CELLS", list_cells)
+        assert K._chunking(K._list_lengths(cfg.n_tasks, cfg.nkv, cfg.nkv,
+                                           ca_pair_bound(cfg, cfg.nkv))[0],
+                           cfg.nkv)[0] > 1
+        split = cad_attention(q, k, v, seg, pos, seg, pos, ctx=ctx)
+        np.testing.assert_array_equal(np.asarray(split), np.asarray(out))
 
 
 def test_dispatch_gradients():

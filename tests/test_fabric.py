@@ -20,6 +20,7 @@ import pytest
 from repro.cad import CADConfig, CADSession
 from repro.core.cost_model import (CalibrationSnapshot, CommModel,
                                    CostModel)
+from repro.core.plan import ca_pair_bound
 from repro.fabric import (LATENCY, SERVE, THROUGHPUT, TRAIN,
                           AdmissionPolicy, FabricExecutor, ServeWorkload,
                           TenantClass, admit_serve)
@@ -219,6 +220,25 @@ def test_workload_build_batch_layout():
     assert (np.asarray(kpos)[1, BLK // 2:] == -1).all()
     with pytest.raises(ValueError, match="slots"):
         wl.build_batch([task(0)] * (wl.slots + 1))
+
+
+def test_workload_groups_fit_the_pair_bound():
+    """Fused serve batches keep the placement order and hold at most
+    ``slots`` tasks whose kv blocks (one pair each) sum to at most the
+    bound the CA-server kernels' pair lists are sized by."""
+    wl = make_workload([(0, 8, 1)], slots=4)
+    tasks = [ServeTaskReq(rid=i, seq=0, q_tokens=1,
+                          kv_tokens=blocks * BLK - 3, arrival_step=0)
+             for i, blocks in enumerate([3, 1, 2, 1, 3, 2])]
+
+    def sizes(bound):
+        return [[-(-t.kv_tokens // BLK) for t in g]
+                for g in wl.groups(tasks, bound)]
+
+    assert sizes(4) == [[3, 1], [2, 1], [3], [2]]
+    assert sizes(100) == [[3, 1, 2, 1], [3, 2]]
+    ex = FabricExecutor(make_session(), wl)
+    assert ex._serve_pairs == ca_pair_bound(make_session().cfg, wl.jmax)
 
 
 def test_workload_rejects_empty_prompt_and_blk_mismatch():

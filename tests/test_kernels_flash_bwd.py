@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import dispatch as D
 from repro.core.attention import mask_fn, ref_attention
 from repro.kernels.packed_flash import kernel as K
 from repro.kernels.packed_flash import ops as O
@@ -291,3 +292,93 @@ def test_ca_server_lse_residual_matches_oracle():
     np.testing.assert_allclose(np.asarray(lse)[live], ref_lse[live],
                                atol=1e-5)
     assert (np.asarray(lse)[~live] == K.LSE_DEAD).all()
+
+
+def _empty_slots(batch):
+    q, kb, vb, st, ln, qp, kp = batch
+    ln = ln.at[1].set(0).at[3].set(0)
+    return q, kb, vb, st, ln, qp.at[1].set(-1).at[3].set(-1), kp
+
+
+def _uncovered_kv(batch):
+    """Every range inside the first half of the kv buffer."""
+    q, kb, vb, st, ln, qp, kp = batch
+    n_half = kb.shape[0] // 2
+    ln = jnp.minimum(ln, n_half)
+    return q, kb, vb, jnp.minimum(st, n_half - ln), ln, qp, kp
+
+
+@pytest.mark.parametrize("case,T,N,seed,fix,jmax,mask_kw", [
+    ("ragged_padded", 6, 8, 0, None, None, {}),
+    ("empty_slots", 6, 8, 1, _empty_slots, None, {}),
+    ("uncovered_kv", 5, 12, 2, _uncovered_kv, None, {}),
+    ("jmax_below_n", 6, 10, 3, None, "max_len", {}),
+    ("sliding", 6, 8, 4, None, None, dict(window=96, sink=16)),
+    ("dilated", 6, 8, 5, None, None, dict(rate=2)),
+])
+@pytest.mark.parametrize("list_cells", [None, 16])
+def test_ca_server_pair_grid_bound_is_bitwise(case, T, N, seed, fix, jmax,
+                                              mask_kw, list_cells,
+                                              monkeypatch):
+    """The pair grids sized by a tight ``max_pairs`` (the covered pairs
+    exactly, so padding cells run too) give bitwise the outputs, lse and
+    gradients of the dense-sized default: the same pairs in the same
+    order, only the padding differs.  With scalar memory for 16 cells
+    both lists run as several calls, which changes no bit either.  All
+    agree with the blockwise XLA server, which walks no pair list."""
+    if list_cells:
+        monkeypatch.setattr(K, "LIST_CELLS", list_cells)
+    batch = make_server_batch(jax.random.PRNGKey(20 + seed), T, 64, 4, 2,
+                              64, N, seed=seed)
+    if fix is not None:
+        batch = fix(batch)
+    q, kb, vb, st, ln, qp, kp = batch
+    lens = np.asarray(ln)
+    if jmax == "max_len":
+        jmax = int(lens.max())
+        assert jmax < N
+    covered = int(np.minimum(lens, jmax or N).sum())
+    tight = K._list_lengths(T, N, jmax or N, covered)
+    dense = K._list_lengths(T, N, jmax or N, None)
+    assert tight[0] < dense[0] and tight[1] < dense[1]
+    if list_cells:
+        assert K._chunking(dense[0], jmax or N)[0] > 1
+        assert K._chunking(dense[1], T)[0] > 1
+    if case == "empty_slots":
+        assert (lens == 0).sum() == 3
+    if case == "uncovered_kv":
+        hit = np.zeros(N, bool)
+        for s, n in zip(np.asarray(st), lens):
+            hit[s:s + n] = True
+        assert not hit[N // 2:].any()
+
+    def both(fn, *args, **kw):
+        return [fn(*args, jmax=jmax, max_pairs=mp, **mask_kw, **kw)
+                for mp in (covered, None)]
+
+    (out, lse), dense = both(K.ca_server_fwd, q, kb, vb, st, ln, qp, kp,
+                             return_lse=True)
+    for a, b in zip((out, lse), dense):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    do = jax.random.normal(jax.random.PRNGKey(seed), out.shape)
+    grads_, dense = both(K.ca_server_bwd, q, kb, vb, out, lse, do, st, ln,
+                         qp, kp)
+    for a, b in zip(grads_, dense):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    xla = dict(jmax=jmax or N, softcap=0.0, scale=None,
+               window=mask_kw.get("window", 0), sink=mask_kw.get("sink", 0),
+               rate=mask_kw.get("rate", 1))
+    want = D._xla_server_fwd_impl(q, kb, vb, st, ln, qp, kp, **xla)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want[0]),
+                               atol=ATOL)
+    live = np.asarray(want[1]) < K.LSE_DEAD / 2
+    np.testing.assert_allclose(np.asarray(lse)[live],
+                               np.asarray(want[1])[live], atol=ATOL)
+    assert (np.asarray(lse)[~live] == K.LSE_DEAD).all()
+    assert_grads_close(grads_, D._xla_server_bwd_impl(
+        (q, kb, vb, st, ln, qp, kp, out, lse), do, None, **xla))
+    # empty task slots and kv slots no range covers still get zeros
+    np.testing.assert_array_equal(np.asarray(out)[lens == 0], 0.0)
+    np.testing.assert_array_equal(np.asarray(grads_[0])[lens == 0], 0.0)
+    if case == "uncovered_kv":
+        np.testing.assert_array_equal(np.asarray(grads_[1])[N // 2:], 0.0)

@@ -70,6 +70,33 @@ def _ca_bwd(q, k, v, lse, start, length, qp, kp):
                            jmax=JMAX, interpret=False)
 
 
+def _ca_cell(jmax, max_pairs, backward):
+    """The CA-server kernels as a training step runs them: pair lists
+    sized by the plan's bound (``plan.ca_pair_bound``; None: dense)."""
+    if backward:
+        def fn(q, k, v, lse, start, length, qp, kp):
+            return K.ca_server_bwd(q, k, v, q, lse, q, start, length, qp,
+                                   kp, jmax=jmax, max_pairs=max_pairs,
+                                   interpret=False)
+    else:
+        def fn(q, k, v, start, length, qp, kp):
+            return K.ca_server_fwd(q, k, v, start, length, qp, kp,
+                                   jmax=jmax, max_pairs=max_pairs,
+                                   interpret=False, return_lse=True)
+    return fn
+
+
+def _ca_cell_shapes(t, n, hq, hkv, dh, backward):
+    """Operands of one nano-batch's CA-task batch on a one-chip server
+    (T = N task and kv slots)."""
+    shapes = [((t, BLK, hq, dh), BF16), ((n, BLK, hkv, dh), BF16),
+              ((n, BLK, hkv, dh), BF16)]
+    if backward:
+        shapes.append(((t, hq, BLK), F32))
+    return shapes + [((t,), I32), ((t,), I32), ((t, BLK), I32),
+                     ((n, BLK), I32)]
+
+
 def _decode(q, kc, vc, req, kv_len, qp):
     return K.ragged_decode_fwd(q, kc, vc, req, kv_len, qp, interpret=False)
 
@@ -112,6 +139,35 @@ CASES = {
     "ca_server_bwd_vmapped": (
         jax.vmap(_ca_bwd),
         _stacked(_CA + [((T, HQ, BLK), F32)] + _CA_META)),
+    # the benchmark's cells, one nano-batch on one chip: smollm-360m
+    # (T 96, jmax 32, 528 pairs) and Mistral-7B (T 192, jmax 64, 2080)
+    "ca_server_fwd_smollm_pairs": (
+        _ca_cell(32, 528, False), _ca_cell_shapes(96, 96, 15, 5, 64, False)),
+    "ca_server_bwd_smollm_pairs": (
+        _ca_cell(32, 528, True), _ca_cell_shapes(96, 96, 15, 5, 64, True)),
+    "ca_server_fwd_mistral_pairs": (
+        _ca_cell(64, 2080, False),
+        _ca_cell_shapes(192, 192, 32, 8, 128, False)),
+    "ca_server_bwd_mistral_pairs": (
+        _ca_cell(64, 2080, True),
+        _ca_cell_shapes(192, 192, 32, 8, 128, True)),
+    # the largest lists the repo's paths reach: a four-server pool of
+    # 32k-token nano-batches (T = N = 1280, jmax 256, 131584 pairs; two
+    # calls a list) in training, in the probes and the elastic runtime,
+    # and a ring pass of it (jmax 64); then the dense lists of one 32k
+    # server (two and three calls)
+    "ca_server_fwd_prolong32k_cad4": (
+        _ca_cell(256, 131584, False),
+        _ca_cell_shapes(1280, 1280, 15, 5, 64, False)),
+    "ca_server_bwd_prolong32k_cad4": (
+        _ca_cell(256, 131584, True),
+        _ca_cell_shapes(1280, 1280, 15, 5, 64, True)),
+    "ca_partial_fwd_prolong32k_ring4": (
+        _ca_cell(64, 131584, False),
+        _ca_cell_shapes(1280, 1280, 15, 5, 64, False)),
+    "ca_server_bwd_prolong32k_dense": (
+        _ca_cell(256, None, True),
+        _ca_cell_shapes(512, 512, 15, 5, 64, True)),
     "ragged_decode_fwd": (_decode, [
         ((8, 1, HQ, DH), BF16), ((8, SEQ, HKV, DH), BF16),
         ((8, SEQ, HKV, DH), BF16), ((8,), I32), ((8,), I32),
