@@ -119,9 +119,10 @@ def run_cell(r: Dict, seed: int, seconds: float, trace: bool, *,
     pk = peaks("TPU v5 lite" if allow_cpu else kind)
     c, mix = r["config"], r["mix"]
     t = c["train"]
-    mc = program.model_config(c)
     ref = compare.reference_module(c)
-    program.check_layout(mc, ref.shapes(c))
+    mc = program.model_config(c, ref)
+    paths = ref.program_paths(c)
+    program.check_layout(mc, ref.shapes(c), paths)
     counter = CompileCounter()
 
     key = jax.random.PRNGKey(seed32(seed, 3))
@@ -139,17 +140,17 @@ def run_cell(r: Dict, seed: int, seconds: float, trace: bool, *,
         rules = make_rules(mesh, mc)
 
     hold = {"params": jax.jit(lambda k: program.to_program(
-        ref.init(c, k), mc.pdtype))(key)}
+        ref.init(c, k), mc.pdtype, paths))(key)}
 
     def first_update(opt_state):
         return {"first_grad": compare.program_first_grad(
-                    program.from_program(opt_state.mu), t["b1"]),
+                    program.from_program(opt_state.mu, paths), t["b1"]),
                 "opt_step": int(opt_state.step)}
 
     def third_update(params, history):
         return {"losses": [h["loss"] for h in history[:window.WARM_STEPS]],
                 "change": compare.program_change(
-                    ref, c, program.from_program(params), key)}
+                    ref, c, program.from_program(params, paths), key)}
 
     profile = {}
 
@@ -233,9 +234,10 @@ def run_cell(r: Dict, seed: int, seconds: float, trace: bool, *,
         span = xplane.host_span(tr, "bench.window")
         lo, hi = span
         ctx = readings.Context(
-            config=c, peaks=pk, chips=chips, n_nano=2, steps=win.steps,
-            window_s=win.window_s, batches=win.window_batches, trace=tr,
-            window_ps=span, spans=profile.get("spans", []))
+            config=c, ref=ref, peaks=pk, chips=chips, n_nano=2,
+            steps=win.steps, window_s=win.window_s,
+            batches=win.window_batches, trace=tr, window_ps=span,
+            spans=profile.get("spans", []))
         values = {}
         for m in r["per_layer"]:
             v = readings.reader(m["name"]).read(ctx)

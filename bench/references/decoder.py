@@ -8,17 +8,130 @@ and positions: query i sees key j iff both lie in the same document
 (segment > 0) and position(j) <= position(i).  Weights are drawn from
 the run's seed by ``init``; ``precision`` rounds every matrix-multiply
 operand to a lower type to make the control (see ``compare``).
+
+A configuration file names its reference module by ``reference``; the
+module is the one place that knows the architecture.  What a reference
+module provides, and who reads each item:
+
+  ``program_fields(c)``  the program's ``ModelConfig`` fields the file
+                         sets on its ``registry_arch`` entry, as plain
+                         values (a dict for a nested config such as
+                         ``moe``): ``harness/program.model_config``
+  ``program_paths(c)``   reference tensor name -> path in the program's
+                         parameter tree (an int is a layer-pattern slot):
+                         ``harness/program`` ``to_program``,
+                         ``from_program``, ``check_layout``
+  ``shapes(c)``, ``init(c, key)``
+                         the tensors and their seeded weights: the
+                         program's initial parameters and the comparison
+  ``loss(c, w, batch, rnd, alter)``, ``lr_at(t, step)``, ``DECAYED``,
+  ``round_to(x, dtype)`` the reference's training steps:
+                         ``harness/compare.reference_readings``
+  ``step_flops(c, batch)``
+                         forward and backward FLOPs of one step, no
+                         remat: the ``step_mfu`` reader
+  ``ca_call_work(c, segment_ids, positions, backward=)``
+                         FLOPs and bytes of one CA-server call of one
+                         layer: ``readings.Context.kernel_roofline``
+                         (``ca_fwd_roofline``, ``ca_bwd_roofline``)
+  ``ca_layers(c)``       layers a step sends to the CA servers: the same
+
+``harness/flops.py`` holds counting helpers such modules share.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from harness import flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_CHUNK = 256
+
+
+# --------------------------------------------------------------- program
+def program_fields(c: Dict) -> Dict:
+    """The ``ModelConfig`` fields of a dense GQA SwiGLU decoder."""
+    return dict(
+        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
+        n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
+        rope_theta=c["rope_theta"],
+        tie_embeddings=c["tie_word_embeddings"],
+        layer_pattern=("global",), activation="silu", gated_mlp=True,
+        norm="rmsnorm", post_norms=False, scale_embed=False, qk_norm=False,
+        use_rope=True, attn_logit_softcap=0.0, final_logit_softcap=0.0,
+        moe=None, ssm=None, rglru=None, encoder=None,
+        param_dtype=c["param_dtype"], compute_dtype=c["compute_dtype"])
+
+
+# where each layer tensor lives in a pattern slot of the program's tree
+SLOT_PATHS = {"norm1": ("norm1", "scale"), "wq": ("attn", "wq"),
+              "wk": ("attn", "wk"), "wv": ("attn", "wv"),
+              "wo": ("attn", "wo"), "norm2": ("norm2", "scale"),
+              "w_gate": ("ffn", "w_gate"), "w_up": ("ffn", "w_up"),
+              "w_down": ("ffn", "w_down")}
+LAYER = tuple(SLOT_PATHS)
+
+
+def program_paths(c: Dict) -> Dict[str, tuple]:
+    """One pattern slot of global layers, stacked on a leading layer
+    axis."""
+    paths = {"embed": ("embed", "embed"), "unembed": ("unembed", "unembed"),
+             "final_norm": ("final_norm", "scale")}
+    paths.update({k: ("blocks", 0) + p for k, p in SLOT_PATHS.items()})
+    return paths
+
+
+# ------------------------------------------------------------------ work
+def linear_params_per_layer(c: Dict) -> int:
+    return flops.gqa_params(c["hidden_size"], c["num_attention_heads"],
+                            c["num_key_value_heads"], c["head_dim"]) \
+        + 3 * c["hidden_size"] * c["intermediate_size"]
+
+
+def linear_flops_per_token(c: Dict) -> int:
+    """Forward FLOPs of the matrix multiplications of one token: 2 x the
+    weights it multiplies through (q/k/v/o, the gated MLP, the output
+    head; the embedding lookup is a gather, 0 FLOPs)."""
+    head = 2 * c["hidden_size"] * c["vocab_size"]
+    return 2 * linear_params_per_layer(c) * c["num_hidden_layers"] + head
+
+
+def attention_fwd_flops(c: Dict, pairs: int) -> int:
+    """Forward FLOPs of core attention over ``pairs`` live pairs, one
+    layer."""
+    return 4 * pairs * c["num_attention_heads"] * c["head_dim"]
+
+
+def step_flops(c: Dict, batch: Dict) -> float:
+    """FLOPs the forward and backward passes of one step need: backward
+    = 2 x forward, work that rematerialisation repeats not counted.
+    Every non-padding token goes through the linear layers."""
+    seg = np.asarray(batch["segment_ids"])
+    tokens = int((seg > 0).sum())
+    pairs = flops.live_pairs(seg, batch["positions"])
+    fwd = tokens * linear_flops_per_token(c) \
+        + c["num_hidden_layers"] * attention_fwd_flops(c, pairs)
+    return 3.0 * fwd
+
+
+def ca_call_work(c: Dict, segment_ids, positions, *,
+                 backward: bool) -> Dict[str, float]:
+    """Work of one CA-server call of one layer (``flops.gqa_call_work``)."""
+    return flops.gqa_call_work(segment_ids, positions,
+                               c["num_attention_heads"],
+                               c["num_key_value_heads"], c["head_dim"],
+                               backward=backward)
+
+
+def ca_layers(c: Dict) -> int:
+    """Every layer is causal full attention: all go to the CA servers."""
+    return c["num_hidden_layers"]
 
 
 # ------------------------------------------------------------------ init
@@ -54,11 +167,18 @@ def fan_in(name: str, shape: tuple) -> Optional[int]:
 
 
 def init(c: Dict, key) -> Dict[str, jnp.ndarray]:
+    """The seed's weights of every tensor of ``shapes(c)``."""
+    return draw(shapes(c), key)
+
+
+def draw(sizes: Dict[str, tuple], key) -> Dict[str, jnp.ndarray]:
     """float32 weights holding bfloat16 values: normal(0, fan_in^-0.5)
-    per tensor from ``fold_in(key, index)``, norm scales 1."""
+    per tensor from ``fold_in(key, index)`` in name order, norm scales 1.
+    The last dotted part of a name is its tensor's kind (``local.wq`` is
+    a ``wq``)."""
     out = {}
-    for i, (name, shp) in enumerate(sorted(shapes(c).items())):
-        fi = fan_in(name, shp)
+    for i, (name, shp) in enumerate(sorted(sizes.items())):
+        fi = fan_in(name.rsplit(".", 1)[-1], shp)
         if fi is None:
             out[name] = jnp.ones(shp, jnp.float32)
         else:
@@ -89,9 +209,10 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(rnd, q, k, v, seg, pos):
+def _attention(rnd, q, k, v, seg, pos, window: int = 0):
     """q [B,S,Hq,dh], k/v [B,S,Hkv,dh] -> [B,S,Hq,dh]; queries in chunks
-    of Q_CHUNK so that the scores of one chunk are all that live."""
+    of Q_CHUNK so that the scores of one chunk are all that live.  With
+    a ``window``, query i also needs position(i) - position(j) < window."""
     b, s, hq, dh = q.shape
     rep = hq // k.shape[2]
     k = jnp.repeat(k, rep, axis=2)
@@ -106,6 +227,8 @@ def _attention(rnd, q, k, v, seg, pos):
         ok = (sc[:, None, :, None] == seg[:, None, None, :]) \
             & (sc[:, None, :, None] > 0) \
             & (pos[:, None, None, :] <= pc[:, None, :, None])
+        if window:
+            ok = ok & (pc[:, None, :, None] - pos[:, None, None, :] < window)
         logits = jnp.where(ok, logits, -jnp.inf)
         m = jnp.max(logits, axis=-1, keepdims=True)
         m = jnp.where(jnp.isfinite(m), m, 0.0)
@@ -121,47 +244,45 @@ def _attention(rnd, q, k, v, seg, pos):
     return out.swapaxes(0, 1).reshape(b, s, hq, dh)
 
 
-def loss(c: Dict, w: Dict, batch: Dict, rnd: Callable = lambda x: x,
-         alter: Optional[Callable] = None):
-    """Mean next-token cross-entropy over label-bearing tokens.  ``alter``
-    (a fault to plant, or None) rewrites each layer's attention output."""
+def block(c: Dict, p: Dict, h, seg, pos, rnd: Callable,
+          alter: Optional[Callable] = None, window: int = 0):
+    """One decoder layer on the residual ``h`` [B,S,D]: attention (under
+    ``window`` where it is not 0) and the gated MLP, each after its
+    RMSNorm.  ``alter`` (a fault to plant, or None) rewrites the
+    attention output."""
     eps, theta = c["rms_norm_eps"], c["rope_theta"]
     hq, hkv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
                    c["head_dim"])
-    seg, pos = batch["segment_ids"], batch["positions"]
     b, s = seg.shape
-    h = w["embed"][batch["tokens"]]
-    layers = {k: w[k] for k in ("norm1", "wq", "wk", "wv", "wo", "norm2",
-                                "w_gate", "w_up", "w_down")}
+    x = _rms(h, p["norm1"], eps)
+    q = _rope(_mm(rnd, x, p["wq"], "bsd,de->bse").reshape(b, s, hq, dh),
+              pos, theta)
+    k = _rope(_mm(rnd, x, p["wk"], "bsd,de->bse").reshape(b, s, hkv, dh),
+              pos, theta)
+    v = _mm(rnd, x, p["wv"], "bsd,de->bse").reshape(b, s, hkv, dh)
+    a = _attention(rnd, q, k, v, seg, pos, window)
+    if alter is not None:
+        a = alter(a)
+    a = a.reshape(b, s, hq * dh)
+    h = h + _mm(rnd, a, p["wo"], "bse,ed->bsd")
 
     @jax.checkpoint
-    def layer(h, p):
-        x = _rms(h, p["norm1"], eps)
-        q = _rope(_mm(rnd, x, p["wq"], "bsd,de->bse").reshape(b, s, hq, dh),
-                  pos, theta)
-        k = _rope(_mm(rnd, x, p["wk"], "bsd,de->bse").reshape(b, s, hkv, dh),
-                  pos, theta)
-        v = _mm(rnd, x, p["wv"], "bsd,de->bse").reshape(b, s, hkv, dh)
-        a = _attention(rnd, q, k, v, seg, pos)
-        if alter is not None:
-            a = alter(a)
-        a = a.reshape(b, s, hq * dh)
-        h = h + _mm(rnd, a, p["wo"], "bse,ed->bsd")
+    def mlp(hr):                          # one row's MLP at a time
+        x = _rms(hr, p["norm2"], eps)
+        g = _mm(rnd, x, p["w_gate"], "sd,df->sf")
+        u = _mm(rnd, x, p["w_up"], "sd,df->sf")
+        return hr + _mm(rnd, jax.nn.silu(g) * u, p["w_down"],
+                        "sf,fd->sd")
+    return jax.lax.map(mlp, h)
 
-        @jax.checkpoint
-        def mlp(hr):                      # one row's MLP at a time
-            x = _rms(hr, p["norm2"], eps)
-            g = _mm(rnd, x, p["w_gate"], "sd,df->sf")
-            u = _mm(rnd, x, p["w_up"], "sd,df->sf")
-            return hr + _mm(rnd, jax.nn.silu(g) * u, p["w_down"],
-                            "sf,fd->sd")
-        return jax.lax.map(mlp, h), None
 
-    h, _ = jax.lax.scan(layer, h, layers)
-    h = _rms(h, w["final_norm"], eps)
+def head_loss(c: Dict, w: Dict, h, batch: Dict, rnd: Callable):
+    """Final norm, output head and the mean next-token cross-entropy over
+    label-bearing tokens of the last residual ``h``."""
+    h = _rms(h, w["final_norm"], c["rms_norm_eps"])
     head = w["embed"] if c["tie_word_embeddings"] else w["unembed"]
     lab = batch["labels"]
-    valid = (lab >= 0) & (seg > 0)
+    valid = (lab >= 0) & (batch["segment_ids"] > 0)
 
     @jax.checkpoint
     def row_nll(args):                   # one row's logits at a time
@@ -175,7 +296,24 @@ def loss(c: Dict, w: Dict, batch: Dict, rnd: Callable = lambda x: x,
     return nll.sum() / jnp.maximum(valid.sum(), 1)
 
 
+def loss(c: Dict, w: Dict, batch: Dict, rnd: Callable = lambda x: x,
+         alter: Optional[Callable] = None):
+    """Mean next-token cross-entropy over label-bearing tokens.  ``alter``
+    (a fault to plant, or None) rewrites each layer's attention output."""
+    seg, pos = batch["segment_ids"], batch["positions"]
+    h = w["embed"][batch["tokens"]]
+
+    @jax.checkpoint
+    def layer(h, p):
+        return block(c, p, h, seg, pos, rnd, alter), None
+
+    h, _ = jax.lax.scan(layer, h, {k: w[k] for k in LAYER})
+    return head_loss(c, w, h, batch, rnd)
+
+
 # ------------------------------------------------------------- optimizer
+# decay applies to every tensor but the final norm's scale (the
+# configuration's stated rule; compare.reference_readings applies it)
 DECAYED = ("embed", "unembed", "norm1", "wq", "wk", "wv", "wo", "norm2",
            "w_gate", "w_up", "w_down")
 
@@ -189,39 +327,3 @@ def lr_at(t: Dict, step):
                                                - t["warmup"], 1), 0.0, 1.0)
     cos = t["peak_lr"] * (0.1 + 0.9 * 0.5 * (1 + jnp.cos(jnp.pi * prog)))
     return jnp.where(step < t["warmup"], warm, cos)
-
-
-def adamw_step(t: Dict, w, m, v, g, step: int):
-    """One AdamW update; gradients clipped by their global norm first.
-    Decay applies to every tensor but the final norm's scale (the
-    configuration's stated rule).  Returns (w, m, v, clipped grads)."""
-    gn = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
-    scale = jnp.minimum(1.0, t["grad_clip"] / (gn + 1e-9))
-    lr = lr_at(t, step)
-    nw, nm, nv, gc = {}, {}, {}, {}
-    for k in w:
-        gk = g[k] * scale
-        nm[k] = t["b1"] * m[k] + (1 - t["b1"]) * gk
-        nv[k] = t["b2"] * v[k] + (1 - t["b2"]) * gk * gk
-        mh = nm[k] / (1 - t["b1"] ** step)
-        vh = nv[k] / (1 - t["b2"] ** step)
-        delta = mh / (jnp.sqrt(vh) + t["eps"])
-        if k in DECAYED:
-            delta = delta + t["weight_decay"] * w[k]
-        nw[k] = w[k] - lr * delta
-        gc[k] = gk
-    return nw, nm, nv, gc
-
-
-def make_step(c: Dict, rnd: Callable = lambda x: x):
-    """A jitted (w, m, v, batch, step) -> (w, m, v, loss, clipped grads)
-    training step of the reference."""
-    t = c["train"]
-
-    @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(0, 1, 2))
-    def step(w, m, v, batch, n):
-        lval, g = jax.value_and_grad(lambda ww: loss(c, ww, batch, rnd))(w)
-        w, m, v, gc = adamw_step(t, w, m, v, g, n)
-        return w, m, v, lval, gc
-
-    return step

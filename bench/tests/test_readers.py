@@ -7,13 +7,14 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from harness import readings, xplane
+from harness import compare, readings, xplane
 from harness.peaks import peaks
 
 DATA = Path(__file__).resolve().parent / "data" / "small.xplane.pb"
 CONFIG = {"num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 64,
           "num_hidden_layers": 1, "hidden_size": 128,
           "intermediate_size": 256, "vocab_size": 256}
+DECODER = compare.reference_module({"reference": "decoder"})
 
 
 def batch():
@@ -27,9 +28,9 @@ def ctx(**over):
     tr = xplane.load(str(DATA))
     lo = min(o.start for o in tr.devices[0].ops)
     hi = xplane.host_span(tr, "host.iter2")[1]
-    kw = dict(config=CONFIG, peaks=peaks("TPU v5 lite"), chips=1, n_nano=1,
-              steps=3, window_s=(hi - lo) / 1e12, batches=[batch()] * 3,
-              trace=tr, window_ps=(lo, hi))
+    kw = dict(config=CONFIG, ref=DECODER, peaks=peaks("TPU v5 lite"),
+              chips=1, n_nano=1, steps=3, window_s=(hi - lo) / 1e12,
+              batches=[batch()] * 3, trace=tr, window_ps=(lo, hi))
     kw.update(over)
     return readings.Context(**kw)
 

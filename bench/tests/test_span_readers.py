@@ -10,8 +10,9 @@ from repro.obs.trace import TraceEvent
 
 
 def ctx(spans):
-    return readings.Context(config={}, peaks={}, chips=1, n_nano=2, steps=3,
-                            window_s=1.0, batches=[], spans=spans)
+    return readings.Context(config={}, ref=None, peaks={}, chips=1,
+                            n_nano=2, steps=3, window_s=1.0, batches=[],
+                            spans=spans)
 
 
 def dispatch(step, fwd, fwd_live, dkv, dkv_live, dur=0.008):

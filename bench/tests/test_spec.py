@@ -1,13 +1,15 @@
-"""BENCHMARK.json resolves to files found by name, and a new cell, mix and
-metric placed as new files are picked up with no existing file edited."""
+"""BENCHMARK.json resolves to files found by name, and a new cell, mix,
+metric and architecture placed as new files are picked up with no
+existing file edited."""
 import json
 import re
 import shutil
+import time
 from pathlib import Path
 
 import pytest
 
-from harness import main, readings
+from harness import compare, main, readings
 
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -96,3 +98,63 @@ def test_new_cell_mix_and_metric_are_picked_up_from_new_files(tmp_path):
     finally:
         readings.METRICS = reader
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+NEW_ARCH = Path(__file__).resolve().parent / "data" / "windowed"
+
+
+def _windowless(monkeypatch):
+    """The program's window removed: its local layers run as global."""
+    import repro.models.model as M
+    real = M.block_apply
+
+    def block_apply(kind, *args, **kw):
+        return real("global" if kind == "local" else kind, *args, **kw)
+    monkeypatch.setattr(M, "block_apply", block_apply)
+
+
+def test_new_architecture_runs_from_new_files(tmp_path, monkeypatch):
+    """A decoder whose layers alternate a 64-token window and full
+    attention, with its own reference module, configuration, mix and
+    limits, all new files in a copy of the benchmark: it runs correct on
+    the CPU through the untouched harness, half of its layers go to the
+    CA servers, and the program without its window is not correct."""
+    new = [f.relative_to(NEW_ARCH) for f in NEW_ARCH.rglob("*")
+           if f.suffix in (".py", ".json")]
+    assert len(new) == 4 and not any((ROOT / "bench" / f).exists()
+                                     for f in new)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench")
+    shutil.copytree(NEW_ARCH, tmp_path / "bench", dirs_exist_ok=True)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cfg = json.loads((NEW_ARCH / "configs" /
+                      "windowed-tiny.json").read_text())
+    spec["configs"].append({"name": cfg["name"], "source": cfg["source"],
+                            "file": "bench/configs/windowed-tiny.json",
+                            "reduced": cfg["reduced"], "why": "test"})
+    cell = "windowed-tiny.tiny-256"
+    spec["workloads"].append({"name": cell, "config": cfg["name"],
+                              "traffic": "tiny-256", "chips": 1,
+                              "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    r = main.resolve(spec, cell, tmp_path)
+    monkeypatch.setattr(compare, "BENCH", tmp_path / "bench")
+    ref = compare.reference_module(r["config"])
+    assert (ref.ca_layers(r["config"]),
+            r["config"]["num_hidden_layers"]) == (1, 2)
+
+    def run():
+        return main.run_cell(r, 2 ** 31 + 20251019, 0.3, False,
+                             t_start=time.perf_counter(),
+                             limits=compare.limits(cell), chips=1,
+                             allow_cpu=True)
+
+    out = run()
+    assert out["correct"], out["checks"]
+    _windowless(monkeypatch)
+    out = run()
+    assert not out["correct"], out["checks"]
+
+    def sources(bench):
+        return {p.relative_to(bench): p.read_bytes()
+                for p in (bench / "harness").rglob("*.py")}
+    assert sources(tmp_path / "bench") == sources(ROOT / "bench")
